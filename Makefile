@@ -1,6 +1,7 @@
 # Canonical entry points for verification and benchmarks.
 #
-#   make test             tier-1 test suite (the CI / verify command)
+#   make test             tier-1 test suite on the CPU (the CI / verify command)
+#   make chip-smoke       fit -> reference -> serve on one TPU (chip_smoke.py)
 #   make test-api         just the unified-API tests (fast)
 #   make lint             dead-import lint (pyflakes when installed, AST fallback)
 #   make ci               lint + tier-1 tests + chaos-smoke + bench-smoke
@@ -38,16 +39,19 @@
 PYTHON ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 
-.PHONY: test test-api lint ci bench bench-smoke bench-transform bench-fit \
+.PHONY: test test-api chip-smoke lint ci bench bench-smoke bench-transform bench-fit \
         bench-serve bench-multiclass bench-streaming bench-online \
         bench-resilience bench-obs bench-gate chaos-smoke serve-smoke \
         continuous-smoke obs-smoke clean dev-deps
 
 test:
-	$(PYTHON) -m pytest -x -q
+	JAX_PLATFORMS=cpu $(PYTHON) -m pytest -x -q
 
 test-api:
-	$(PYTHON) -m pytest -q tests/test_api.py
+	JAX_PLATFORMS=cpu $(PYTHON) -m pytest -q tests/test_api.py
+
+chip-smoke:
+	$(PYTHON) chip_smoke.py
 
 lint:
 	$(PYTHON) tools/lint.py src/repro benchmarks tools

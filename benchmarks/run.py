@@ -33,6 +33,7 @@ from . import (
     roofline,
 )
 from .common import Reporter
+from repro.launch import compile_cache
 
 BENCHES = {
     "fig1_bound": bench_bound.run,
@@ -53,6 +54,12 @@ BENCHES = {
     "roofline": roofline.run,
 }
 
+# Benchmarks that start child processes which need the accelerator.  They
+# run first, before this process initializes a JAX backend: a process that
+# has touched JAX holds the chip, and a child that needs it then fails or
+# hangs.
+SPAWNS_DEVICE_CHILDREN = ("resilience_chaos",)
+
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
@@ -63,6 +70,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     names = list(BENCHES) if not args.only else args.only.split(",")
+    names.sort(key=lambda name: name not in SPAWNS_DEVICE_CHILDREN)  # stable
+    compile_cache.enable()
     rep = Reporter()
     t0 = time.time()
     for name in names:

@@ -974,10 +974,11 @@ def eval_with_constants(consts: PlanConstants, Z) -> jax.Array:
     sequential steps instead of O(|O|)) plus one matmul.  Pure and
     traceable: callers wrap it in ``jax.jit`` and/or ``shard_map``."""
     cols = apply_wavefronts(Z, consts.waves)  # (q, L) in wavefront order
-    lead = (cols @ jnp.asarray(consts.GPsel, Z.dtype)) * (
-        Z @ jnp.asarray(consts.GVsel, Z.dtype)
+    hi = jax.lax.Precision.HIGHEST  # f32 selects and sums, also on TPU
+    lead = jnp.matmul(cols, jnp.asarray(consts.GPsel, Z.dtype), precision=hi) * (
+        jnp.matmul(Z, jnp.asarray(consts.GVsel, Z.dtype), precision=hi)
     )
-    return jnp.abs(cols @ jnp.asarray(consts.C_w, Z.dtype) + lead)
+    return jnp.abs(jnp.matmul(cols, jnp.asarray(consts.C_w, Z.dtype), precision=hi) + lead)
 
 
 def _make_fused_eval(plan: "_FusedPlan"):

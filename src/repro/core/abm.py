@@ -51,8 +51,9 @@ def _make_degree_step(cfg: ABMConfig, reduce_fn=None):
 
         P = jnp.take(A, parents, axis=1)
         B = P * jnp.take(X, vars_, axis=1)
-        QL = rfn(A.T @ B) * inv_m  # (L, K)
-        C = rfn(B.T @ B) * inv_m  # (K, K)
+        hi = jax.lax.Precision.HIGHEST  # f32 Grams, also on TPU
+        QL = rfn(jnp.matmul(A.T, B, precision=hi)) * inv_m  # (L, K)
+        C = rfn(jnp.matmul(B.T, B, precision=hi)) * inv_m  # (K, K)
 
         def body(a, carry):
             AtA_c, ell, accepted, slots, coeffs, lams = carry

@@ -111,7 +111,9 @@ def _init_batch_arrays(mask, Lcap: int, factors):
     eager form, so the values are bit-identical."""
     k = mask.shape[0]
     dtype = mask.dtype
-    A = jnp.zeros((k, mask.shape[1], Lcap), dtype).at[:, :, 0].set(mask)
+    # padding keeps the mask's sharding: a row-sharded mask gives a
+    # row-sharded A, never a whole A on one device
+    A = jnp.pad(mask[:, :, None], ((0, 0), (0, 0), (0, Lcap - 1)))
     # normalized Gram convention: AtA[0,0] = ||mask_c||^2 / m_c = 1 per class
     state = ihb_mod.batch_state(
         ihb_mod.init_state(Lcap, jnp.asarray(1.0, dtype), dtype, factors=factors),
@@ -235,23 +237,25 @@ def fit_classes(
         for c, X in enumerate(Xp):
             Xstack[c, : ms[c]] = X
             mask[c, : ms[c]] = 1.0
-        Xd = jnp.asarray(Xstack)
         Lcap = pow2_bucket(config.cap_terms)
-        A, state = _init_batch_arrays(
-            jnp.asarray(mask), Lcap, config.ihb_factors()
-        )
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             from . import distributed as distributed_mod
 
+            # host -> row shards directly: no device ever holds all rows
             bspec = NamedSharding(mesh, distributed_mod.class_data_spec(data_axes))
             rep = NamedSharding(mesh, P())
-            Xd = jax.device_put(Xd, bspec)
-            A = jax.device_put(A, bspec)
-            state = jax.device_put(state, rep)
+            Xd = jax.device_put(Xstack, bspec)
+            mask_d = jax.device_put(mask, NamedSharding(mesh, P(*bspec.spec[:2])))
         else:
             bspec = rep = None
+            Xd = jnp.asarray(Xstack)
+            mask_d = jnp.asarray(mask)
+        A, state = _init_batch_arrays(mask_d, Lcap, config.ihb_factors())
+        if mesh is not None:
+            A = jax.device_put(A, bspec)
+            state = jax.device_put(state, rep)
 
         books = [terms_mod.TermBook(n=n) for _ in range(k)]
         generators: List[List[Generator]] = [[] for _ in range(k)]
@@ -298,11 +302,9 @@ def fit_classes(
 
             # shared capacity: regrow when the largest class overflows
             while max(ells[c] + Ks[c] for c in range(k)) > Lcap:
+                A = jnp.pad(A, ((0, 0), (0, 0), (0, Lcap)))  # keeps A's sharding
                 Lcap *= 2
                 scope.regrowth(Lcap)
-                A = jax.lax.dynamic_update_slice(
-                    jnp.zeros((k, mc, Lcap), dtype), A, (0, 0, 0)
-                )
                 state = ihb_mod.grow_state(state, Lcap)
                 if mesh is not None:
                     A = jax.device_put(A, bspec)

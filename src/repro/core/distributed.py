@@ -38,17 +38,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map (with check_vma) is only public in newer jax; older releases
-# ship it as jax.experimental.shard_map.shard_map (with check_rep).  Shared
-# with repro.serving.engine, which wraps the fused transform the same way.
-if hasattr(jax, "shard_map"):
-    shard_map_compat = jax.shard_map
-    SHARD_MAP_KW = {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map as shard_map_compat
-
-    SHARD_MAP_KW = {"check_rep": False}
-
 from . import ihb as ihb_mod
 from . import terms as terms_mod
 from .. import obs
@@ -125,12 +114,12 @@ def make_sharded_degree_step(
     dspec = data_spec(axes)
     rep = P()
 
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(dspec, dspec, rep, rep, rep, rep, rep, rep),
         out_specs=(dspec, rep),
-        **SHARD_MAP_KW,
+        check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -163,12 +152,12 @@ def make_class_batched_sharded_degree_step(
     bspec = class_data_spec(axes)
     rep = P()
 
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(bspec, bspec, rep, rep, rep, rep, rep, rep),
         out_specs=(bspec, rep),
-        **SHARD_MAP_KW,
+        check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -189,8 +178,10 @@ def shard_samples(
     mask = np.zeros((m_pad, 1), dtype=np.float32)
     mask[:m] = 1.0
     dspec = data_spec(data_axes)
-    xs = jax.device_put(jnp.asarray(Xp, dtype), NamedSharding(mesh, dspec))
-    ms = jax.device_put(jnp.asarray(mask, dtype), NamedSharding(mesh, dspec))
+    # host -> row shards directly: no device ever holds all rows
+    np_dtype = np.dtype(jnp.dtype(dtype))
+    xs = jax.device_put(Xp.astype(np_dtype, copy=False), NamedSharding(mesh, dspec))
+    ms = jax.device_put(mask.astype(np_dtype, copy=False), NamedSharding(mesh, dspec))
     return xs, ms, m
 
 
@@ -229,9 +220,9 @@ def fit(
         dspec = data_spec(data_axes)
         a_shard = NamedSharding(mesh, dspec)
         rep = NamedSharding(mesh, P())
-        # constant column = sample mask (zero on padded rows)
-        A = jnp.zeros((m_pad, Lcap), dtype).at[:, 0:1].set(mask)
-        A = jax.device_put(A, a_shard)
+        # constant column = sample mask (zero on padded rows); padding keeps
+        # the row sharding of the mask on any mesh axis type
+        A = jax.device_put(jnp.pad(mask, ((0, 0), (0, Lcap - 1))), a_shard)
         # normalized convention: AtA[0,0] = ||mask||^2 / m = 1
         state = ihb_mod.init_state(
             Lcap, jnp.asarray(1.0, dtype), dtype, factors=config.ihb_factors()
@@ -263,14 +254,9 @@ def fit(
 
             # capacity management: device-side regrowth into the next pow2 bucket
             while ell + K > Lcap:
+                A = jax.device_put(jnp.pad(A, ((0, 0), (0, Lcap))), a_shard)
                 Lcap *= 2
                 scope.regrowth(Lcap)
-                A = jax.device_put(
-                    jax.lax.dynamic_update_slice(
-                        jnp.zeros((m_pad, Lcap), dtype), A, (0, 0)
-                    ),
-                    a_shard,
-                )
                 state = jax.device_put(ihb_mod.grow_state(state, Lcap), rep)
 
             Kcap = max(config.cap_border, pow2_bucket(K))

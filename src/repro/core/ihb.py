@@ -21,7 +21,8 @@ configuration to its minimal factor set, and :func:`append_column` skips the
 default for direct users of this module.
 
 The ``N`` update is dispatched through :func:`repro.kernels.ops.ihb_update`
-(the fused Pallas kernel on TPU, its bit-identical jnp reference elsewhere).
+(the Pallas kernel on TPU, its jnp reference elsewhere, or whichever the
+caller's ``OAVIConfig.kernel`` names).
 
 Beyond the paper, the Cholesky-factor engine (maintain the upper-triangular
 ``R`` with ``A^T A = R^T R``; appends are triangular solves) has conditioning
@@ -38,6 +39,9 @@ import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular
 
 from ..kernels import ops as kernel_ops
+
+# Matvecs at full f32 precision: TPU's default rounds f32 operands to bf16.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class IHBState(NamedTuple):
@@ -126,7 +130,13 @@ def batch_state(state: IHBState, k: int) -> IHBState:
 
 def closed_form_inverse(state: IHBState, q: jax.Array) -> jax.Array:
     """``y* = -N q`` (paper's IHB warm start).  ``q = A^T b`` padded."""
-    return -(state.N @ q)
+    return -inverse_times(state, q)
+
+
+def inverse_times(state: IHBState, q: jax.Array) -> jax.Array:
+    """``u = N q``: the warm start's matvec, which the Theorem 4.9 update
+    (:func:`append_column`) reuses."""
+    return jnp.matmul(state.N, q, precision=_HIGHEST)
 
 
 def closed_form_cholesky(state: IHBState, q: jax.Array) -> jax.Array:
@@ -154,6 +164,9 @@ def append_column(
     q: jax.Array,  # (L,) A^T b for the new column b (zeros at inactive idx)
     btb: jax.Array,  # ||b||^2
     ell: jax.Array,  # current active count == index where b lands
+    *,
+    u: Optional[jax.Array] = None,  # N q, when the caller already formed it
+    kernel_kw: Optional[dict] = None,  # ops.ihb_update dispatch (OAVIConfig.kernel)
 ) -> IHBState:
     """Theorem 4.9 block inverse update + Cholesky append, both O(l^2).
 
@@ -178,15 +191,17 @@ def append_column(
         )
 
     if state.N is not None:
-        # ---- inverse update (Thm 4.9) — the fused kernel on TPU, its
-        # bit-identical jnp reference elsewhere.
-        N = kernel_ops.ihb_update(state.N, q, btb, ell)
+        # ---- inverse update (Thm 4.9): the Pallas kernel or its jnp
+        # reference, as ``kernel_kw`` (or the backend) selects.
+        N = kernel_ops.ihb_update(state.N, q, btb, ell, u=u, **(kernel_kw or {}))
 
     if state.R is not None:
         # ---- Cholesky append: R^T r = q ; rho = sqrt(btb - r^T r)
         r = solve_triangular(state.R, q, trans=1, lower=False)
         r = r * keep  # the inactive identity block must not leak into r
-        rho2 = jnp.maximum(btb - r @ r, jnp.asarray(1e-30, dtype))
+        rho2 = jnp.maximum(
+            btb - jnp.dot(r, r, precision=_HIGHEST), jnp.asarray(1e-30, dtype)
+        )
         rho = jnp.sqrt(rho2)
         col = r + rho * onehot
         # overwrite column ell of R (previously e_ell from the identity padding)
@@ -198,4 +213,4 @@ def append_column(
 def schur_complement(state: IHBState, q: jax.Array, btb: jax.Array) -> jax.Array:
     """``s = ||b||^2 - q^T N q`` — the (INF)/singularity guard of §4.4.3:
     if s <= 0 the new column is (numerically) dependent and IHB must stop."""
-    return btb - q @ (state.N @ q)
+    return btb - jnp.dot(q, inverse_times(state, q), precision=_HIGHEST)

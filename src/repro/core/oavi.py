@@ -82,6 +82,10 @@ from .ordering import pearson_order
 
 _SOLVER_FNS = {"agd": solve_agd, "cg": solve_cg, "pcg": solve_pcg, "bpcg": solve_bpcg}
 
+# Matmuls at full f32 precision: TPU's default rounds f32 operands to bf16,
+# which moves transform outputs by ~1e-2 relative (see PERF.md).
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _np_dtype(dtype) -> np.dtype:
     """``np.dtype`` for possibly-extension dtype names (``"bfloat16"``):
@@ -104,8 +108,9 @@ class OAVIConfig:
     dtype: str = "float32"
     ordering: str = "pearson"  # 'pearson' | 'none' | 'reverse_pearson'
     tol_dependent: float = 1e-9  # Schur-complement guard (relative)
-    # Gram kernel dispatch: 'auto' (Pallas on TPU, jnp elsewhere), 'pallas',
-    # 'interpret' (Pallas in interpreter mode — tests), 'jnp' (force fallback)
+    # dispatch of both kernels (Gram and IHB update): 'auto' (Pallas on TPU,
+    # jnp elsewhere), 'pallas', 'interpret' (Pallas in interpreter mode —
+    # tests), 'jnp' (the pure-jnp references)
     kernel: str = "auto"
 
     def jax_dtype(self):
@@ -178,7 +183,7 @@ class OAVIModel:
             return jnp.zeros((Z.shape[0], 0), self.dtype)
         C, gp, gv = self.generator_arrays()
         lead = cols[:, gp] * Z[:, gv]  # leading-term columns
-        return cols @ jnp.asarray(C) + lead
+        return jnp.matmul(cols, jnp.asarray(C), precision=_HIGHEST) + lead
 
     def mse(self, Z: jax.Array) -> jax.Array:
         """Per-generator MSE over Z."""
@@ -355,8 +360,9 @@ def apply_wavefronts(Z, waves, perm=None) -> jax.Array:
 
     The column selections are expressed as one-hot matmuls (the same
     gather-as-matmul trick as the gram kernel): exact for any dtype (each
-    output sums one value plus hard zeros), MXU-friendly on TPU, and far
-    faster than XLA's scalar gathers on CPU.
+    output sums one value plus hard zeros) at ``HIGHEST`` precision — the
+    TPU's default would round the selected values to bf16 — MXU-friendly on
+    TPU, and far faster than XLA's scalar gathers on CPU.
     """
     prev = jnp.ones((Z.shape[0], 1), Z.dtype)
     blocks = [prev]
@@ -368,7 +374,9 @@ def apply_wavefronts(Z, waves, perm=None) -> jax.Array:
         Psel[pos, np.arange(k)] = 1.0
         Vsel = np.zeros((n, k), np.float32)
         Vsel[var, np.arange(k)] = 1.0
-        prev = (prev @ jnp.asarray(Psel, Z.dtype)) * (Z @ jnp.asarray(Vsel, Z.dtype))
+        prev = jnp.matmul(prev, jnp.asarray(Psel, Z.dtype), precision=_HIGHEST) * (
+            jnp.matmul(Z, jnp.asarray(Vsel, Z.dtype), precision=_HIGHEST)
+        )
         blocks.append(prev)
         prev_size = k
     cols = jnp.concatenate(blocks, axis=1) if len(blocks) > 1 else blocks[0]
@@ -415,7 +423,7 @@ def evaluate_terms(Z: jax.Array, parents, vars_) -> jax.Array:
     try:
         parents_np = np.asarray(parents)
         vars_np = np.asarray(vars_)
-    except Exception:  # traced indices (inside someone else's jit)
+    except jax.errors.TracerArrayConversionError:  # traced indices (inside a jit)
         return evaluate_terms_sequential(Z, parents, vars_)
     return make_wavefront_evaluator(parents_np, vars_np)(jnp.asarray(Z))
 
@@ -481,6 +489,7 @@ def _make_stats_degree_step(cfg: OAVIConfig, reduce_fn=None, schedule=None):
     # closed-form optimum needed: always for 'fast', as a warm start otherwise
     need_closed_form = (not engine_oracle) or cfg.ihb
     rfn = reduce_fn if reduce_fn is not None else (lambda x: x)
+    kernel_kw = _kernel_kwargs(cfg)
 
     def stats_step(QL_raw, C_raw, state: ihb_mod.IHBState, ell0, valid, m_total):
         dtype = cfg.jax_dtype()
@@ -506,11 +515,13 @@ def _make_stats_degree_step(cfg: OAVIConfig, reduce_fn=None, schedule=None):
             btb = C[a, a]
 
             mask = jnp.arange(Lcap) < st.ell
+            u = None  # N q: shared by the warm start and the Thm 4.9 update
             if need_closed_form:
                 if use_chol:
                     y0 = ihb_mod.closed_form_cholesky(st.ihb, q)
                 else:
-                    y0 = ihb_mod.closed_form_inverse(st.ihb, q)
+                    u = ihb_mod.inverse_times(st.ihb, q)
+                    y0 = -u
                 y0 = jnp.where(mask, y0, 0.0)
 
             unconverged = st.unconverged
@@ -570,7 +581,9 @@ def _make_stats_degree_step(cfg: OAVIConfig, reduce_fn=None, schedule=None):
             do_append = (~accept) & valid[a]
 
             def appended(st_in: _LoopState):
-                new_ihb = ihb_mod.append_column(st_in.ihb, q, btb, st_in.ell)
+                new_ihb = ihb_mod.append_column(
+                    st_in.ihb, q, btb, st_in.ell, u=u, kernel_kw=kernel_kw
+                )
                 return st_in._replace(
                     ihb=new_ihb,
                     ell=st_in.ell + 1,

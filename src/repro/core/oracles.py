@@ -122,6 +122,12 @@ class SolveResult(NamedTuple):
     converged: jax.Array = True
 
 
+def _mv(Q, y):
+    """Gram matvec at full f32 precision (TPU's default matmul precision
+    rounds f32 operands to bf16, enough to move a solve across psi)."""
+    return jnp.matmul(Q, y, precision=jax.lax.Precision.HIGHEST)
+
+
 def vdot(a, b):
     """Vector dot as elementwise multiply + reduce — the vmap-bit-stable
     lowering (a fused ``a @ b`` reduces in a different order when batched;
@@ -130,17 +136,17 @@ def vdot(a, b):
 
 
 def quad_f(Q, q, btb, inv_m, y):
-    return (vdot(y, Q @ y) + 2.0 * vdot(q, y) + btb) * inv_m
+    return (vdot(y, _mv(Q, y)) + 2.0 * vdot(q, y) + btb) * inv_m
 
 
 def quad_grad(Q, q, inv_m, y):
-    return 2.0 * inv_m * (Q @ y + q)
+    return 2.0 * inv_m * (_mv(Q, y) + q)
 
 
 def _line_search_quad(Q, inv_m, grad, d, gamma_max):
     """Exact line search for the quadratic along ``d``; clipped to
     ``[0, gamma_max]``.  f(y + g d) - f(y) = g <grad, d> + g^2 d^T Q d / m."""
-    dQd = vdot(d, Q @ d) * inv_m
+    dQd = vdot(d, _mv(Q, d)) * inv_m
     num = -vdot(grad, d)
     gamma = jnp.where(dQd > 0, num / jnp.maximum(2.0 * dQd, 1e-30), gamma_max)
     return jnp.clip(gamma, 0.0, gamma_max)
@@ -180,12 +186,12 @@ def _estimate_lmax(Q, mask, iters: int):
     v0 = v0 / jnp.maximum(jnp.sqrt(vdot(v0, v0)), 1e-30)
 
     def body(_, v):
-        w = Q @ v
+        w = _mv(Q, v)
         nrm = jnp.sqrt(vdot(w, w))
         return jnp.where(nrm > 0, w / jnp.maximum(nrm, 1e-30), v)
 
     v = jax.lax.fori_loop(0, iters, body, v0)
-    return jnp.maximum(vdot(v, Q @ v), 1e-30)
+    return jnp.maximum(vdot(v, _mv(Q, v)), 1e-30)
 
 
 def _agd_parts(Q, q, btb, m, mask, psi, cfg: OracleConfig, y0):
@@ -307,7 +313,7 @@ def _fw_state0(Q, q, btb, inv_m, y0, wp0, wm0, mask, r):
     IHB-warm fits at budget 0 (certificate check only) instead of paying a
     full masked iteration per lane just to learn the gap."""
     maskf = mask.astype(Q.dtype)
-    Qy = Q @ y0  # shared between f0 and grad: one matvec, not two
+    Qy = _mv(Q, y0)  # shared between f0 and grad: one matvec, not two
     f0 = (vdot(y0, Qy) + 2.0 * vdot(q, y0) + btb) * inv_m
     grad = (2.0 * inv_m) * (Qy + q) * maskf
     i, val = _fw_vertex(grad, mask, r)

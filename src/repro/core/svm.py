@@ -44,12 +44,12 @@ class LinearSVMConfig:
 def _squared_hinge_grad(W, b, Xb, Y):
     """Mean squared-hinge loss + gradients.  Y in {-1, +1}, shape (m, k)."""
     m = Xb.shape[0]
-    scores = Xb @ W + b  # (m, k)
+    scores = jnp.matmul(Xb, W, precision=jax.lax.Precision.HIGHEST) + b  # (m, k)
     margin = 1.0 - Y * scores
     active = jnp.maximum(margin, 0.0)
     loss = jnp.mean(jnp.sum(active * active, axis=1))
     g_scores = (-2.0 / m) * (active * Y)  # (m, k)
-    gW = Xb.T @ g_scores
+    gW = jnp.matmul(Xb.T, g_scores, precision=jax.lax.Precision.HIGHEST)
     gb = jnp.sum(g_scores, axis=0)
     return loss, gW, gb
 
@@ -109,10 +109,15 @@ class LinearSVM:
         Xb = jnp.concatenate([X, jnp.ones((m, 1), dt)], axis=1)
         # power iteration for the top singular value
         v = jnp.ones((Xb.shape[1],), dt)
+        hi = jax.lax.Precision.HIGHEST  # f32 matvecs, also on TPU
+
+        def gram_v(v):
+            return jnp.matmul(Xb.T, jnp.matmul(Xb, v, precision=hi), precision=hi)
+
         for _ in range(20):
-            v = Xb.T @ (Xb @ v)
+            v = gram_v(v)
             v = v / jnp.maximum(jnp.linalg.norm(v), 1e-30)
-        lmax = v @ (Xb.T @ (Xb @ v))
+        lmax = jnp.dot(v, gram_v(v), precision=hi)
         step = 1.0 / jnp.maximum(2.0 * lmax / m, 1e-12)
         W, b, iters = _fista(
             X, Y, jnp.asarray(self.config.lam, dt), step,

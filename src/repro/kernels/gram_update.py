@@ -34,43 +34,42 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _gram_kernel(a_ref, x_ref, psel_ref, vsel_ref, ql_ref, c_ref):
-    """One m-block: fused select-matmul, product, and Gram accumulation."""
-    step = pl.program_id(0)
+def _dot(a, b):
+    # full f32 precision: the TPU's default rounds f32 operands to bf16,
+    # which moves both Grams (and with them accept/reject decisions)
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
 
-    @pl.when(step == 0)
+
+def _accumulate_block(a_ref, x_ref, psel_ref, vsel_ref, ql_ref, c_ref):
+    """One m-block: fused select-matmul, product, and Gram accumulation."""
+    a = a_ref[...]  # (bm, L)
+    # gather-as-matmul: parent columns and variable columns for all K cands
+    b = _dot(a, psel_ref[...]) * _dot(x_ref[...], vsel_ref[...])  # (bm, K)
+    ql_ref[...] += _dot(a.T, b)
+    c_ref[...] += _dot(b.T, b)
+
+
+def _gram_kernel(a_ref, x_ref, psel_ref, vsel_ref, ql_ref, c_ref):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         ql_ref[...] = jnp.zeros_like(ql_ref)
         c_ref[...] = jnp.zeros_like(c_ref)
 
-    a = a_ref[...]  # (bm, L)
-    x = x_ref[...]  # (bm, n)
-    # gather-as-matmul: parent columns and variable columns for all K cands
-    parents = jnp.dot(a, psel_ref[...], preferred_element_type=jnp.float32)
-    varcols = jnp.dot(x, vsel_ref[...], preferred_element_type=jnp.float32)
-    b = parents * varcols  # (bm, K) candidate columns
-    ql_ref[...] += jnp.dot(a.T, b, preferred_element_type=jnp.float32)
-    c_ref[...] += jnp.dot(b.T, b, preferred_element_type=jnp.float32)
+    _accumulate_block(a_ref, x_ref, psel_ref, vsel_ref, ql_ref, c_ref)
 
 
 def _gram_acc_kernel(a_ref, x_ref, psel_ref, vsel_ref, ql0_ref, c0_ref, ql_ref, c_ref):
     """Carry-in variant: the accumulators start from ``(ql0, c0)`` instead of
     zero, so a stream of calls over row chunks reduces in exactly the same
     block order as one call over the concatenated rows (out-of-core OAVI)."""
-    step = pl.program_id(0)
 
-    @pl.when(step == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         ql_ref[...] = ql0_ref[...]
         c_ref[...] = c0_ref[...]
 
-    a = a_ref[...]  # (bm, L)
-    x = x_ref[...]  # (bm, n)
-    parents = jnp.dot(a, psel_ref[...], preferred_element_type=jnp.float32)
-    varcols = jnp.dot(x, vsel_ref[...], preferred_element_type=jnp.float32)
-    b = parents * varcols  # (bm, K) candidate columns
-    ql_ref[...] += jnp.dot(a.T, b, preferred_element_type=jnp.float32)
-    c_ref[...] += jnp.dot(b.T, b, preferred_element_type=jnp.float32)
+    _accumulate_block(a_ref, x_ref, psel_ref, vsel_ref, ql_ref, c_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))

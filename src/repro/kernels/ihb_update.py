@@ -1,19 +1,26 @@
 """IHB block-inverse update (Theorem 4.9) as a Pallas TPU kernel.
 
 The O(l^2) hot path of Inverse Hessian Boosting: given ``N = (A^T A)^{-1}``
-(padded to capacity L with an identity block), the new column's Gram vector
-``q = A^T b`` and squared norm ``btb``, produce the updated inverse after
-appending column ``b`` at slot ``ell``:
+(padded to capacity L with an identity block), ``u = N q`` for the new
+column's Gram vector ``q = A^T b``, and the Schur complement
+``s = ||b||^2 - q^T u``, produce the updated inverse after appending column
+``b`` at slot ``ell``:
 
-    u  = N q
-    s  = btb - q^T u              (Schur complement)
     N' = [[N + u u^T / s, -u/s], [-u^T/s, 1/s]]   (written in place at slot ell)
 
-A single-block kernel: everything fits VMEM for L <= ~1024 (L^2 fp32 = 4 MB
-at L=1024).  The matvec ``N q`` runs on the MXU; the rank-one update is a
-VPU outer product.  Masking with the ``ell`` one-hot keeps the padded
-identity block intact, exactly like :func:`repro.core.ihb.append_column`
-(the ref oracle).
+``u`` is an input rather than recomputed here: the degree step already
+forms ``N q`` for the IHB warm start, so the kernel is a pure rank-one
+update plus one row/column write — elementwise work on the VPU.
+
+The grid runs over ``bl``-row blocks of ``N``, so VMEM holds
+``O(bl * L)`` floats per step instead of the whole ``(L, L)`` inverse (which
+no longer fits at L = 2048, nor at L = 1024 under a class-batched ``vmap``).
+The slot ``ell`` is scalar-prefetched into SMEM; ``s`` rides as a ``(1, 1)``
+VMEM block (a float scalar cannot be prefetched, and a ``(1, 1)`` block
+stays legal when ``vmap`` adds a batch axis).  Masking with the ``ell``
+one-hot keeps the padded identity block intact, exactly like the jnp
+reference :func:`repro.kernels.ref.ihb_update_ref`, whose arithmetic the
+kernel repeats operation for operation.
 """
 
 from __future__ import annotations
@@ -23,50 +30,66 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Bytes of one (bl, L) f32 block of N: the input and output blocks are each
+# double-buffered, so a step holds about 4x this in VMEM.
+_BLOCK_BYTES = 1 << 20
 
 
-def _ihb_kernel(n_ref, q_ref, scal_ref, out_ref):
-    N = n_ref[...]  # (L, L)
-    q = q_ref[...]  # (1, L) row vector
-    btb = scal_ref[0, 0]
-    ell = scal_ref[0, 1].astype(jnp.int32)
-    L = N.shape[0]
+def row_block(L: int) -> int:
+    """Rows of ``N`` per grid step: a power of two (``L`` is one), at least
+    8 (the f32 sublane tile) and at most ``L``."""
+    return max(min(L, _BLOCK_BYTES // (4 * L)), min(L, 8))
 
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (1, L), 1) == ell).astype(N.dtype)
-    u = jnp.dot(q, N.T, preferred_element_type=jnp.float32)  # (1, L) = (N q)^T
-    s = btb - jnp.sum(q * u)
-    s = jnp.maximum(s, jnp.asarray(1e-30, N.dtype))
-    P = N + jnp.dot(u.T, u, preferred_element_type=jnp.float32) / s
-    keep = 1.0 - onehot  # zero out row/col ell (currently identity)
-    P = P * keep.T * keep
-    n2 = -u / s
-    out_ref[...] = (
-        P
-        + jnp.dot(onehot.T, n2, preferred_element_type=jnp.float32)
-        + jnp.dot(n2.T, onehot, preferred_element_type=jnp.float32)
-        + (1.0 / s) * jnp.dot(onehot.T, onehot, preferred_element_type=jnp.float32)
-    )
+
+def _ihb_kernel(ell_ref, s_ref, n_ref, ucol_ref, urow_ref, out_ref):
+    bl, L = n_ref.shape
+    ell = ell_ref[0]  # scalar-prefetched into SMEM
+    s = s_ref[...]  # (1, 1)
+    rows = pl.program_id(0) * bl + jax.lax.broadcasted_iota(jnp.int32, (bl, 1), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, L), 1)
+    u_col = ucol_ref[...]  # (bl, 1): this block's slice of u
+    u_row = urow_ref[...]  # (1, L): all of u
+    dt = u_row.dtype
+    # new row / column ell (diagonal 1/s), as a column slice and a full row
+    c_col = (-u_col / s) * (rows != ell).astype(dt) + (rows == ell).astype(dt) / s
+    c_row = (-u_row / s) * (cols != ell).astype(dt) + (cols == ell).astype(dt) / s
+    P = n_ref[...] + (u_col * u_row) / s
+    out_ref[...] = jnp.where(rows == ell, c_row, jnp.where(cols == ell, c_col, P))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ihb_update(
     N: jax.Array,  # (L, L) current padded inverse
-    q: jax.Array,  # (L,) A^T b (zeros at inactive slots)
-    btb: jax.Array,  # scalar ||b||^2
+    u: jax.Array,  # (L,) N q (zeros at inactive slots)
+    s: jax.Array,  # scalar Schur complement, already floored > 0
     ell: jax.Array,  # scalar int: append slot
     *,
     interpret: bool = False,
 ) -> jax.Array:
     L = N.shape[0]
-    scal = jnp.stack([btb.astype(N.dtype), ell.astype(N.dtype)]).reshape(1, 2)
+    bl = row_block(L)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(L // bl,),
+        in_specs=[
+            pl.BlockSpec((1, 1), lambda i, ell: (0, 0)),
+            pl.BlockSpec((bl, L), lambda i, ell: (i, 0)),
+            pl.BlockSpec((bl, 1), lambda i, ell: (i, 0)),
+            pl.BlockSpec((1, L), lambda i, ell: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((bl, L), lambda i, ell: (i, 0)),
+    )
     return pl.pallas_call(
         _ihb_kernel,
-        in_specs=[
-            pl.BlockSpec((L, L), lambda: (0, 0)),
-            pl.BlockSpec((1, L), lambda: (0, 0)),
-            pl.BlockSpec((1, 2), lambda: (0, 0), memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((L, L), lambda: (0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((L, L), N.dtype),
         interpret=interpret,
-    )(N, q.reshape(1, L), scal)
+    )(
+        ell.astype(jnp.int32).reshape(1),
+        s.astype(N.dtype).reshape(1, 1),
+        N,
+        u.reshape(L, 1),
+        u.reshape(1, L),
+    )

@@ -1,11 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Each op pads its inputs to kernel-aligned shapes, dispatches to the Pallas
-kernel on TPU (or ``interpret=True`` when requested), and falls back to the
-pure-jnp reference on backends without Pallas-TPU support (this container's
-CPU, and the dry-run's 512 fake CPU devices).  The fallback is semantically
-identical — ``ref.py`` *is* the spec — so models can be built against these
-ops unconditionally.
+Each op pads its inputs to kernel-aligned shapes and runs one of three
+implementations: the Pallas kernel (``use_pallas=True``), the same kernel in
+interpreter mode (``interpret=True``, tests), or the pure-jnp reference
+(``use_pallas=False``).  ``use_pallas=None`` picks the kernel exactly when
+JAX's default backend is a TPU and the reference otherwise (CPU, and the
+dry-run's fake CPU devices); an error from the backend query propagates.
+Both implementations have the same semantics — ``ref.py`` *is* the spec.
 """
 
 from __future__ import annotations
@@ -27,10 +28,7 @@ GRAM_BLOCK = 256
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # backend not initialized yet
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -104,15 +102,19 @@ def gram_accumulate(
     return _gram_acc_kernel(A, X, Psel, Vsel, acc[0], acc[1], bm=bm, interpret=interpret)
 
 
-def ihb_update(N, q, btb, ell, *, use_pallas=None, interpret=False):
-    """Theorem 4.9 padded block-inverse update."""
+def ihb_update(N, q, btb, ell, *, u=None, use_pallas=None, interpret=False):
+    """Theorem 4.9 padded block-inverse update.  ``u = N q`` is formed here
+    unless the caller passes it (the degree step already has it from the IHB
+    warm start)."""
     if use_pallas is None:
         use_pallas = _on_tpu()
     if not (use_pallas or interpret):
-        return ref.ihb_update_ref(N, q, btb, ell)
-    return _ihb_kernel(
-        N, q, jnp.asarray(btb, N.dtype), jnp.asarray(ell, jnp.int32), interpret=interpret
-    )
+        return ref.ihb_update_ref(N, q, btb, ell, u=u)
+    if u is None:
+        u = jnp.matmul(N, q, precision=jax.lax.Precision.HIGHEST)
+    # Schur complement, reduced exactly as the reference reduces it
+    s = jnp.maximum(btb - jnp.sum(q * u), jnp.asarray(1e-30, N.dtype))
+    return _ihb_kernel(N, u, s, jnp.asarray(ell, jnp.int32), interpret=interpret)
 
 
 def multihead_attention(

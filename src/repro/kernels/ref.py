@@ -10,13 +10,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# The references compute at full f32 precision (TPU's default matmul
+# precision rounds f32 operands to bf16).
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def gram_update_ref(A, X, Psel, Vsel):
     """(QL, C): candidate columns via one-hot selection, then both Grams."""
-    B = (A @ Psel) * (X @ Vsel)
+    B = jnp.matmul(A, Psel, precision=_HIGHEST) * jnp.matmul(X, Vsel, precision=_HIGHEST)
     Af = A.astype(jnp.float32)
     Bf = B.astype(jnp.float32)
-    return Af.T @ Bf, Bf.T @ Bf
+    return jnp.matmul(Af.T, Bf, precision=_HIGHEST), jnp.matmul(Bf.T, Bf, precision=_HIGHEST)
 
 
 def gram_update_gather_ref(A, X, parents, vars_):
@@ -31,7 +35,7 @@ def gram_update_gather_ref(A, X, parents, vars_):
     B = jnp.take(A, parents, axis=1) * jnp.take(X, vars_, axis=1)
     Af = A.astype(jnp.float32)
     Bf = B.astype(jnp.float32)
-    return Af.T @ Bf, Bf.T @ Bf
+    return jnp.matmul(Af.T, Bf, precision=_HIGHEST), jnp.matmul(Bf.T, Bf, precision=_HIGHEST)
 
 
 def border_columns_ref(A, X, parents, vars_):
@@ -62,8 +66,8 @@ def gram_accumulate_ref(A, X, parents, vars_, ql0, c0, *, bm: int):
     B = jnp.take(A, parents, axis=1) * jnp.take(X, vars_, axis=1)
     Af = A.astype(jnp.float32).reshape(nb, bm, A.shape[1])
     Bf = B.astype(jnp.float32).reshape(nb, bm, B.shape[1])
-    QLb = jnp.einsum("bmi,bmj->bij", Af, Bf)
-    Cb = jnp.einsum("bmi,bmj->bij", Bf, Bf)
+    QLb = jnp.einsum("bmi,bmj->bij", Af, Bf, precision=_HIGHEST)
+    Cb = jnp.einsum("bmi,bmj->bij", Bf, Bf, precision=_HIGHEST)
 
     def body(carry, blocks):
         ql, c = carry
@@ -74,9 +78,10 @@ def gram_accumulate_ref(A, X, parents, vars_, ql0, c0, *, bm: int):
     return ql, c
 
 
-def ihb_update_ref(N, q, btb, ell):
+def ihb_update_ref(N, q, btb, ell, u=None):
     """Theorem 4.9 block-inverse update on the padded inverse (identity in
     the inactive block) — mirrors :func:`repro.core.ihb.append_column`.
+    ``u = N q`` is formed here unless the caller already has it.
 
     Contract (what every in-algorithm caller satisfies): ``q`` is zero at
     slot ``ell`` and beyond (A has no active columns there) and row/col
@@ -97,7 +102,8 @@ def ihb_update_ref(N, q, btb, ell):
     L = N.shape[0]
     onehot = (jnp.arange(L) == ell).astype(dtype)
     keep = 1.0 - onehot
-    u = N @ q
+    if u is None:
+        u = jnp.matmul(N, q, precision=_HIGHEST)
     s = jnp.maximum(btb - jnp.sum(q * u), jnp.asarray(1e-30, dtype))
     n2 = -u / s
     P = N + jnp.outer(u, u) / s

@@ -445,4 +445,7 @@ def main(argv=None) -> Dict:
 
 
 if __name__ == "__main__":
+    from . import compile_cache
+
+    compile_cache.enable()
     main()

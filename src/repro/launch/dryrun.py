@@ -95,7 +95,7 @@ def lower_cell(
             in_shardings=(pshard, sshard, bshard),
             out_shardings=(NamedSharding(mesh, P()), pshard, sshard),
         )
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = fn.lower(aparams, astate, abatch)
     elif shape.kind == "prefill":
         def prefill_step(params, batch):
@@ -108,7 +108,7 @@ def lower_cell(
             in_shardings=(pshard, bshard),
             out_shardings=(NamedSharding(mesh, P()), cshard),
         )
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = fn.lower(aparams, abatch)
     else:  # decode
         acache = M.abstract_cache(cfg, shape.global_batch, shape.seq_len)
@@ -123,7 +123,7 @@ def lower_cell(
             out_shardings=(NamedSharding(mesh, P()), cshard),
             donate_argnums=(1,),
         )
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = fn.lower(
                 aparams, acache, abatch["token"], abatch["pos"]
             )
@@ -237,7 +237,7 @@ def run_oavi_cell(mesh, mesh_name: str, *, m_global: int = 4_194_304,
     )
     i32 = jnp.int32
     t0 = time.perf_counter()
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = step.lower(
             aA, aX, astate,
             jax.ShapeDtypeStruct((), i32),
@@ -334,4 +334,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from . import compile_cache
+
+    compile_cache.enable()
     main()

@@ -50,7 +50,7 @@ def serve(
     decode = jax.jit(lambda p, c, t, pos: M.decode_step(p, c, t, pos, cfg))
 
     t0 = time.perf_counter()
-    with mesh:
+    with jax.set_mesh(mesh):
         logits, cache = prefill(params, {"tokens": prompts})
     t_prefill = time.perf_counter() - t0
 
@@ -59,7 +59,7 @@ def serve(
     t0 = time.perf_counter()
     for i in range(gen_tokens - 1):
         pos = jnp.full((batch,), prompt_len + i, jnp.int32)
-        with mesh:
+        with jax.set_mesh(mesh):
             logits, cache = decode(params, cache, tok, pos)
         if greedy:
             tok = jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
@@ -97,4 +97,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from . import compile_cache
+
+    compile_cache.enable()
     main()

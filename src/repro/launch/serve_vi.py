@@ -86,8 +86,10 @@ def replay(
 ) -> Dict:
     """Closed-loop replay: ``concurrency`` clients each send their share of
     the trace, one in-flight request per client.  Returns latency/throughput
-    stats (latencies in ms)."""
+    stats (latencies in ms) and each request's answer (``outputs``, in
+    trace order)."""
     latencies = [0.0] * len(payloads)
+    outputs: List = [None] * len(payloads)
     errors: List[BaseException] = []
     next_idx = {"i": 0}
     idx_lock = threading.Lock()
@@ -101,7 +103,7 @@ def replay(
                 next_idx["i"] = i + 1
             t0 = time.perf_counter()
             try:
-                batcher.submit(payloads[i], kind).result()
+                outputs[i] = batcher.submit(payloads[i], kind).result()
             except BaseException as e:  # surfaced after the run
                 errors.append(e)
                 return
@@ -131,6 +133,7 @@ def replay(
         "lat_p99_ms": lat["p99"],
         "lat_p999_ms": lat["p999"],
         "lat_max_ms": lat["max"],
+        "outputs": outputs,
     }
 
 
@@ -208,12 +211,13 @@ def main(argv=None) -> Dict:
     rng = np.random.default_rng(args.seed + 1)
     from ..data.synthetic import appendix_c
 
-    pool, _ = appendix_c(m=max(sizes), seed=args.seed + 2)
-    pool = entry.scale(pool)  # scale once; requests are slices of the pool
-    payloads = []
+    raw_pool, _ = appendix_c(m=max(sizes), seed=args.seed + 2)
+    pool = entry.scale(raw_pool)  # scale once; requests are slices of the pool
+    payloads, raw_payloads = [], []
     for q in sizes:
         take = rng.integers(0, pool.shape[0] - q + 1)
         payloads.append(pool[take : take + q])
+        raw_payloads.append(raw_pool[take : take + q])
 
     batcher = MicroBatcher(
         engine,
@@ -228,6 +232,8 @@ def main(argv=None) -> Dict:
     # -- report -----------------------------------------------------------
     es, bs = engine.stats, batcher.stats
     report.update(
+        kind=kind,
+        inputs=raw_payloads,  # unscaled request rows, in trace order
         recompiles=es["recompiles"],
         device_calls=es["device_calls"],
         padded_rows=es["padded_rows"],
@@ -255,4 +261,7 @@ def main(argv=None) -> Dict:
 
 
 if __name__ == "__main__":
+    from . import compile_cache
+
+    compile_cache.enable()
     main()

@@ -38,13 +38,13 @@ def make_sharded_train_state(cfg, opt, mesh, seed: int = 0):
     pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
                           is_leaf=lambda x: isinstance(x, P))
     init = jax.jit(partial(M.init_params, cfg=cfg), out_shardings=pshard)
-    with mesh:
+    with jax.set_mesh(mesh):
         params = init(jax.random.PRNGKey(seed))
     sspecs = opt.state_specs(pspecs)
     sshard = jax.tree.map(lambda s: NamedSharding(mesh, s), sspecs,
                           is_leaf=lambda x: isinstance(x, P))
     opt_init = jax.jit(opt.init, out_shardings=sshard)
-    with mesh:
+    with jax.set_mesh(mesh):
         opt_state = opt_init(params)
     return params, opt_state, pshard, sshard
 
@@ -94,7 +94,7 @@ def train(
         return {"tokens": tokens}
 
     def wrapped_step(state, batch):
-        with mesh:
+        with jax.set_mesh(mesh):
             loss, params, opt_state = step_fn(state["params"], state["opt"], batch)
         losses.append(float(loss))
         return {"params": params, "opt": opt_state}, {"loss": float(loss)}
@@ -142,4 +142,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from . import compile_cache
+
+    compile_cache.enable()
     main()

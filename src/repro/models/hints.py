@@ -21,28 +21,14 @@ from jax.sharding import PartitionSpec as P
 
 
 def current_mesh():
-    """The mesh this trace is running under, or None."""
-    try:
-        m = jax._src.mesh.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and am.axis_names:
-            return am
-    except Exception:
-        pass
-    return None
+    """The mesh set by ``jax.set_mesh`` for this trace, or None."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am
 
 
 def axis_sizes() -> Dict[str, int]:
     m = current_mesh()
-    if m is None:
-        return {}
-    return {name: int(size) for name, size in zip(m.axis_names, m.shape.values())} \
-        if hasattr(m.shape, "values") else dict(m.shape)
+    return {} if m is None else dict(m.shape)
 
 
 def batch_axes() -> Tuple[str, ...]:
@@ -70,7 +56,12 @@ def constrain(x, *spec):
             clean.append(kept if kept else None)
         else:
             clean.append(s if s in sizes else None)
-    return jax.lax.with_sharding_constraint(x, P(*clean))
+    spec = P(*clean)
+    # Explicit mesh axes (jax.make_mesh's default) carry the sharding in the
+    # array type and move it with reshard; Auto axes take a constraint
+    if jax.sharding.AxisType.Explicit in current_mesh().axis_types:
+        return jax.sharding.reshard(x, spec)
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def constrain_batch(x):

@@ -34,10 +34,8 @@ import numpy as np
 
 from .. import obs
 from ..core.distributed import (
-    SHARD_MAP_KW,
     data_spec,
     num_data_shards,
-    shard_map_compat,
 )
 from ..core.oavi import pow2_bucket
 from ..resilience import chaos
@@ -182,12 +180,12 @@ class TransformEngine:
         if self.mesh is None:
             return jax.jit(eval_fn)
         dspec = data_spec(self.data_axes)
-        sharded = shard_map_compat(
+        sharded = jax.shard_map(
             eval_fn,
             mesh=self.mesh,
             in_specs=(dspec,),
             out_specs=dspec,
-            **SHARD_MAP_KW,
+            check_vma=False,
         )
         return jax.jit(sharded)
 

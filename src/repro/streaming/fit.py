@@ -51,10 +51,8 @@ from ..kernels import ops as kernel_ops
 from ..core import ihb as ihb_mod
 from ..core import terms as terms_mod
 from ..core.distributed import (
-    SHARD_MAP_KW,
     data_spec,
     num_data_shards,
-    shard_map_compat,
     shard_probe,
 )
 from ..core.oavi import (
@@ -218,12 +216,12 @@ def _chunk_accumulator(
             return ql[None], c[None]
 
         fn = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 per_shard,
                 mesh=mesh,
                 in_specs=(aspec, aspec, dspec2, dspec1, rep, rep),
                 out_specs=(aspec, aspec),
-                **SHARD_MAP_KW,
+                check_vma=False,
             ),
             donate_argnums=(0, 1),
         )
@@ -317,12 +315,12 @@ def _streaming_stats_entry(
         per_shard = shard_probe(per_shard, mesh, axes, "fit/shard_step")
 
         return jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 per_shard,
                 mesh=mesh,
                 in_specs=(aspec, aspec, rep, rep, rep, rep),
                 out_specs=rep,
-                **SHARD_MAP_KW,
+                check_vma=False,
             )
         )
 

@@ -90,9 +90,9 @@ def test_gram_property_symmetry_psd(seed):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("L,ell", [(8, 3), (16, 7), (32, 20), (64, 1)])
-def test_ihb_update_vs_ref(L, ell):
-    rng = np.random.default_rng(L * 31 + ell)
+def _ihb_problem(L, ell, seed):
+    """Padded inverse, Gram vector and squared norm of a random append."""
+    rng = np.random.default_rng(seed)
     m = 200
     Araw = rng.uniform(0, 1, (m, ell)).astype(np.float32)
     G = Araw.T @ Araw / m + 1e-3 * np.eye(ell, dtype=np.float32)
@@ -102,9 +102,42 @@ def test_ihb_update_vs_ref(L, ell):
     q = np.zeros(L, np.float32)
     q[:ell] = Araw.T @ b / m
     btb = np.float32(b @ b / m)
-    got = ops.ihb_update(jnp.asarray(N), jnp.asarray(q), btb, ell, interpret=True)
-    want = ref.ihb_update_ref(jnp.asarray(N), jnp.asarray(q), btb, ell)
+    return jnp.asarray(N), jnp.asarray(q), btb
+
+
+@pytest.mark.parametrize(
+    "L,ell", [(8, 3), (16, 7), (32, 20), (64, 1), (64, 40), (256, 100)]
+)
+def test_ihb_update_vs_ref(L, ell):
+    N, q, btb = _ihb_problem(L, ell, L * 31 + ell)
+    got = ops.ihb_update(N, q, btb, ell, interpret=True)
+    want = ref.ihb_update_ref(N, q, btb, ell)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    # the caller's N q (the degree step's warm-start matvec) is used as is
+    got_u = ops.ihb_update(N, q, btb, ell, u=N @ q, interpret=True)
+    np.testing.assert_allclose(got_u, want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("L", [64, 256])
+def test_ihb_update_vmapped_vs_ref(L):
+    """Class-batched use: the kernel under vmap, one append slot per lane."""
+    probs = [_ihb_problem(L, ell, L + ell) for ell in (1, L // 4, L // 2 + 3)]
+    N = jnp.stack([p[0] for p in probs])
+    q = jnp.stack([p[1] for p in probs])
+    btb = jnp.asarray([p[2] for p in probs])
+    ell = jnp.asarray([1, L // 4, L // 2 + 3], jnp.int32)
+    got = jax.vmap(lambda *a: ops.ihb_update(*a, interpret=True))(N, q, btb, ell)
+    want = jax.vmap(ref.ihb_update_ref)(N, q, btb, ell)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("L", [64, 2048])
+def test_ihb_row_block_bounds_vmem(L):
+    from repro.kernels.ihb_update import row_block
+
+    bl = row_block(L)
+    assert L % bl == 0 and bl >= min(L, 8)
+    assert bl * L * 4 <= 1 << 20  # one (bl, L) f32 block of N
 
 
 # ---------------------------------------------------------------------------

@@ -58,3 +58,28 @@ def test_serve_rejects_encoder_only():
     cfg = configs.get_reduced("hubert-xlarge")
     with pytest.raises(ValueError, match="encoder-only"):
         serve(cfg, batch=1, prompt_len=4, gen_tokens=2)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_location(tmp_path, from_env):
+    """The CLIs' compile cache goes to $JAX_COMPILATION_CACHE_DIR when set
+    (the code sets nothing) and to <repo>/.jax_cache otherwise.  Checked in
+    a child process: the tests themselves never turn the cache on."""
+    import json
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"), JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    code = (
+        "import json, jax; from repro.launch import compile_cache; "
+        "d = compile_cache.enable(); "
+        "print(json.dumps([d, jax.config.jax_compilation_cache_dir]))"
+    )
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    returned, configured = json.loads(out.stdout.strip().splitlines()[-1])
+    want = str(tmp_path / "cache") if from_env else os.path.join(repo, ".jax_cache")
+    assert returned == configured == want

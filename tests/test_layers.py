@@ -68,7 +68,7 @@ def test_hints_noop_without_mesh():
 
 def test_hints_active_under_mesh():
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with mesh:
+    with jax.set_mesh(mesh):
         sizes = hints.axis_sizes()
         assert sizes == {"data": 1, "model": 1}
         x = jnp.ones((4, 8))

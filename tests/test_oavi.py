@@ -142,6 +142,35 @@ def test_degree_step_parity_pallas_interpret(planted_cube):
         assert ga.mse == gb.mse
 
 
+@pytest.mark.parametrize("kernel,expect", [
+    ("jnp", {"use_pallas": False}),
+    ("interpret", {"interpret": True}),
+    ("auto", {}),
+])
+def test_kernel_config_reaches_ihb_update(planted_cube, monkeypatch, kernel, expect):
+    """OAVIConfig.kernel steers the IHB update as well as the Gram op, and
+    the update receives the warm start's N q instead of recomputing it."""
+    from repro.kernels import ops
+
+    seen = []
+    real = ops.ihb_update
+
+    def spy(N, q, btb, ell, **kw):
+        seen.append(kw)
+        return real(N, q, btb, ell, **kw)
+
+    monkeypatch.setattr(ops, "ihb_update", spy)
+    cfg = dataclasses.replace(_cfg(), kernel=kernel, ordering="none")
+    model = oavi.fit(
+        planted_cube[:256], cfg,
+        _degree_step_factory=lambda: oavi._make_degree_step(cfg),
+    )
+    assert model.num_O > 1 and seen  # something was appended
+    for kw in seen:
+        assert kw.pop("u") is not None
+        assert kw == expect
+
+
 def test_gram_fallback_bit_exact_vs_inline_matmul(planted_cube):
     """ops.gram_update's gather fallback == the pre-PR inline formulation
     ``(A^T B, B^T B)`` with gathered candidate columns, bit for bit."""
