@@ -239,10 +239,9 @@ def _device_rows(model: oavi.OAVIModel, eng: TransformEngine) -> list:
     """What the device-level flight recorder costs, and what it recorded.
 
     The fit/serve overhead sections above already price the *whole* obs
-    stack (device capture included) against the disabled path; these rows
-    break out the two device-specific costs — per-signature HLO cost
-    capture and per-boundary memory sampling — and assert the stats
-    contract (every fit/serve stats dict carries the device fields).
+    stack against the disabled path; these rows break out the per-boundary
+    memory sampling and assert the stats contract (every fit/serve stats
+    dict carries its compile accounting).
     """
     from repro.obs import device as obs_device
 
@@ -253,12 +252,9 @@ def _device_rows(model: oavi.OAVIModel, eng: TransformEngine) -> list:
     for _ in range(n_samples):
         obs_device.sample_memory(mem_stats)
     t_sample = (time.perf_counter() - t0) / n_samples
-    cap = obs_device.capture_stats()
-    assert "flops_per_degree" in model.stats, "fit stats lost flops_per_degree"
     assert "compile_seconds" in model.stats, "fit stats lost compile_seconds"
     eng_stats = eng.stats
-    assert "achieved_gflops" in eng_stats, "engine stats lost achieved_gflops"
-    fit_flops = [f for f in model.stats["flops_per_degree"] if f]
+    assert "compile_seconds" in eng_stats, "engine stats lost compile_seconds"
     return [
         {
             "section": "device",
@@ -269,22 +265,9 @@ def _device_rows(model: oavi.OAVIModel, eng: TransformEngine) -> list:
         },
         {
             "section": "device",
-            "metric": "cost_capture",
-            "captures": int(cap["captures"]),
-            "failures": int(cap["failures"]),
-            "total_capture_s": round(cap["seconds"], 4),
-            "mean_capture_ms": round(
-                cap["seconds"] / max(cap["captures"], 1) * 1e3, 3
-            ),
-        },
-        {
-            "section": "device",
             "metric": "stats_contract",
-            "fit_degrees_with_cost": len(fit_flops),
-            "fit_flops_total": float(sum(fit_flops)),
             "fit_compile_seconds": float(model.stats["compile_seconds"]),
-            "serve_flops_dispatched": float(eng_stats["flops_dispatched"]),
-            "serve_achieved_gflops": float(eng_stats["achieved_gflops"] or 0.0),
+            "serve_compile_seconds": float(eng_stats["compile_seconds"]),
         },
     ]
 

@@ -1057,47 +1057,49 @@ def feature_transform(
         if dtype is not None:
             out = np.asarray(out).astype(np.dtype(dtype), copy=False)
         return jax.device_put(out, out_sharding) if out_sharding is not None else out
-    plan, fused_eval = _fused_plan_and_eval(models) if models else (None, None)
+    with obs.span("transform/plan"):
+        plan, fused_eval = _fused_plan_and_eval(models) if models else (None, None)
     if plan is None:
         out = _legacy_feature_transform(models, Z, dtype=dtype)
         return jax.device_put(out, out_sharding) if out_sharding is not None else out
-    Z = np.asarray(Z)
-    q = Z.shape[0]
-    out_dtype = np.dtype(dtype) if dtype is not None else plan.dtype
-    if plan.num_features == 0:
-        out = np.zeros((q, 0), out_dtype)
+    with obs.span("transform/eval"):
+        Z = np.asarray(Z)
+        q = Z.shape[0]
+        out_dtype = np.dtype(dtype) if dtype is not None else plan.dtype
+        if plan.num_features == 0:
+            out = np.zeros((q, 0), out_dtype)
+            return jax.device_put(out, out_sharding) if out_sharding is not None else out
+        Zd = Z.astype(plan.dtype, copy=False)
+        if batch_size is None or batch_size >= q:
+            if q == 1:
+                # XLA lowers single-row matmuls as gemv with a different
+                # accumulation pattern than the q >= 2 gemm path; evaluate at
+                # q=2 so direct, chunked and serving-bucket paths all see the
+                # same row-stable lowering (bit-identical results).
+                pad = np.zeros((2, Z.shape[1]), plan.dtype)
+                pad[:1] = Zd
+                out = fused_eval(jnp.asarray(pad))[:1]
+            else:
+                out = fused_eval(jnp.asarray(Zd))
+            if out_sharding is not None:
+                return jax.device_put(out, out_sharding)
+            return np.asarray(out).astype(out_dtype, copy=False)
+        out = np.empty((q, plan.num_features), out_dtype)
+        # chunks must be >= 2 rows so no chunk hits the single-row gemv lowering
+        # (see the q == 1 branch above); the output rows are unchanged
+        batch_size = max(batch_size, 2)
+        for start in range(0, q, batch_size):
+            chunk = Zd[start : start + batch_size]
+            if chunk.shape[0] < batch_size:  # pad trailing chunk: one trace only
+                pad = np.zeros((batch_size, Z.shape[1]), plan.dtype)
+                pad[: chunk.shape[0]] = chunk
+                res = fused_eval(jnp.asarray(pad))[: chunk.shape[0]]
+            else:
+                res = fused_eval(jnp.asarray(chunk))
+            out[start : start + batch_size] = np.asarray(res).astype(
+                out_dtype, copy=False
+            )
         return jax.device_put(out, out_sharding) if out_sharding is not None else out
-    Zd = Z.astype(plan.dtype, copy=False)
-    if batch_size is None or batch_size >= q:
-        if q == 1:
-            # XLA lowers single-row matmuls as gemv with a different
-            # accumulation pattern than the q >= 2 gemm path; evaluate at
-            # q=2 so direct, chunked and serving-bucket paths all see the
-            # same row-stable lowering (bit-identical results).
-            pad = np.zeros((2, Z.shape[1]), plan.dtype)
-            pad[:1] = Zd
-            out = fused_eval(jnp.asarray(pad))[:1]
-        else:
-            out = fused_eval(jnp.asarray(Zd))
-        if out_sharding is not None:
-            return jax.device_put(out, out_sharding)
-        return np.asarray(out).astype(out_dtype, copy=False)
-    out = np.empty((q, plan.num_features), out_dtype)
-    # chunks must be >= 2 rows so no chunk hits the single-row gemv lowering
-    # (see the q == 1 branch above); the output rows are unchanged
-    batch_size = max(batch_size, 2)
-    for start in range(0, q, batch_size):
-        chunk = Zd[start : start + batch_size]
-        if chunk.shape[0] < batch_size:  # pad trailing chunk: one trace only
-            pad = np.zeros((batch_size, Z.shape[1]), plan.dtype)
-            pad[: chunk.shape[0]] = chunk
-            res = fused_eval(jnp.asarray(pad))[: chunk.shape[0]]
-        else:
-            res = fused_eval(jnp.asarray(chunk))
-        out[start : start + batch_size] = np.asarray(res).astype(
-            out_dtype, copy=False
-        )
-    return jax.device_put(out, out_sharding) if out_sharding is not None else out
 
 
 __all__ = [

@@ -209,73 +209,74 @@ def fit_classes(
     }
     scope = FitScope(batch, backend="class_batch")
     with scope:
-        # per-class Pearson ordering (each class permutes its own features)
-        perms: List[Optional[np.ndarray]] = []
-        Xp: List[np.ndarray] = []
-        for X in Xs:
-            perm = None
-            if config.ordering in ("pearson", "reverse_pearson"):
-                perm = pearson_order(X, reverse=(config.ordering == "reverse_pearson"))
-                X = X[:, perm]
-            perms.append(perm)
-            Xp.append(X)
+        with obs.span("fit/prepare"):
+            # per-class Pearson ordering (each class permutes its own features)
+            perms: List[Optional[np.ndarray]] = []
+            Xp: List[np.ndarray] = []
+            for X in Xs:
+                perm = None
+                if config.ordering in ("pearson", "reverse_pearson"):
+                    perm = pearson_order(X, reverse=(config.ordering == "reverse_pearson"))
+                    X = X[:, perm]
+                perms.append(perm)
+                Xp.append(X)
 
-        shards = 1
-        if mesh is not None:
-            from . import distributed as distributed_mod
+            shards = 1
+            if mesh is not None:
+                from . import distributed as distributed_mod
 
-            shards = distributed_mod.num_data_shards(mesh, data_axes)
-        mc = m_cap if m_cap is not None else pow2_bucket(max(ms))
-        mc = _round_up(max(mc, max(ms)), shards)
-        batch["m_cap"] = int(mc)
+                shards = distributed_mod.num_data_shards(mesh, data_axes)
+            mc = m_cap if m_cap is not None else pow2_bucket(max(ms))
+            mc = _round_up(max(mc, max(ms)), shards)
+            batch["m_cap"] = int(mc)
 
-        # stacked rows + per-class row masks (mask IS the constant column, so
-        # padded rows are zero in every column of A)
-        np_dt = _np_dtype(config.dtype)
-        Xstack = np.zeros((k, mc, n), np_dt)
-        mask = np.zeros((k, mc), np_dt)
-        for c, X in enumerate(Xp):
-            Xstack[c, : ms[c]] = X
-            mask[c, : ms[c]] = 1.0
-        Lcap = pow2_bucket(config.cap_terms)
-        if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
+            # stacked rows + per-class row masks (mask IS the constant column, so
+            # padded rows are zero in every column of A)
+            np_dt = _np_dtype(config.dtype)
+            Xstack = np.zeros((k, mc, n), np_dt)
+            mask = np.zeros((k, mc), np_dt)
+            for c, X in enumerate(Xp):
+                Xstack[c, : ms[c]] = X
+                mask[c, : ms[c]] = 1.0
+            Lcap = pow2_bucket(config.cap_terms)
+            if mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec as P
 
-            from . import distributed as distributed_mod
+                from . import distributed as distributed_mod
 
-            # host -> row shards directly: no device ever holds all rows
-            bspec = NamedSharding(mesh, distributed_mod.class_data_spec(data_axes))
-            rep = NamedSharding(mesh, P())
-            Xd = jax.device_put(Xstack, bspec)
-            mask_d = jax.device_put(mask, NamedSharding(mesh, P(*bspec.spec[:2])))
-        else:
-            bspec = rep = None
-            Xd = jnp.asarray(Xstack)
-            mask_d = jnp.asarray(mask)
-        A, state = _init_batch_arrays(mask_d, Lcap, config.ihb_factors())
-        if mesh is not None:
-            A = jax.device_put(A, bspec)
-            state = jax.device_put(state, rep)
+                # host -> row shards directly: no device ever holds all rows
+                bspec = NamedSharding(mesh, distributed_mod.class_data_spec(data_axes))
+                rep = NamedSharding(mesh, P())
+                Xd = jax.device_put(Xstack, bspec)
+                mask_d = jax.device_put(mask, NamedSharding(mesh, P(*bspec.spec[:2])))
+            else:
+                bspec = rep = None
+                Xd = jnp.asarray(Xstack)
+                mask_d = jnp.asarray(mask)
+            A, state = _init_batch_arrays(mask_d, Lcap, config.ihb_factors())
+            if mesh is not None:
+                A = jax.device_put(A, bspec)
+                state = jax.device_put(state, rep)
 
-        books = [terms_mod.TermBook(n=n) for _ in range(k)]
-        generators: List[List[Generator]] = [[] for _ in range(k)]
-        ells = [1] * k
-        active = [True] * k
+            books = [terms_mod.TermBook(n=n) for _ in range(k)]
+            generators: List[List[Generator]] = [[] for _ in range(k)]
+            ells = [1] * k
+            active = [True] * k
 
-        # Fixed-schedule solver budget (oracle/WIHB configs): starts at the
-        # config's pow2 bucket, doubles whenever any lane's solve was cut
-        # short, persists across degrees (like capacity, it only grows).
-        schedule = (
-            oracles_mod.schedule_budget(config.solver)
-            if needs_solver_schedule(config)
-            else None
-        )
-        batch["solver_schedule_len"] = schedule
-        batch["solver_escalations"] = 0
+            # Fixed-schedule solver budget (oracle/WIHB configs): starts at the
+            # config's pow2 bucket, doubles whenever any lane's solve was cut
+            # short, persists across degrees (like capacity, it only grows).
+            schedule = (
+                oracles_mod.schedule_budget(config.solver)
+                if needs_solver_schedule(config)
+                else None
+            )
+            batch["solver_schedule_len"] = schedule
+            batch["solver_escalations"] = 0
 
-        m_total = jnp.asarray([float(m) for m in ms], dtype)
+            m_total = jnp.asarray([float(m) for m in ms], dtype)
 
-        per_class = [init_fit_stats(ms[c], n) for c in range(k)]
+            per_class = [init_fit_stats(ms[c], n) for c in range(k)]
 
         d = 0
         while any(active):
@@ -285,44 +286,45 @@ def fit_classes(
                     if active[c]:
                         per_class[c]["termination"] = f"max_degree={config.max_degree}"
                 break
-            borders: List[List] = []
-            for c in range(k):
-                b = books[c].border(d) if active[c] else []
-                if active[c] and not b:
-                    active[c] = False
-                    per_class[c]["termination"] = "empty_border"
-                borders.append(b)
-            if not any(active):
-                break
-            Ks = [len(b) for b in borders]
-            for c in range(k):
-                if borders[c]:
-                    per_class[c]["border_sizes"].append(Ks[c])
-                    per_class[c]["degrees"].append(d)
+            with obs.span("fit/border"):
+                borders: List[List] = []
+                for c in range(k):
+                    b = books[c].border(d) if active[c] else []
+                    if active[c] and not b:
+                        active[c] = False
+                        per_class[c]["termination"] = "empty_border"
+                    borders.append(b)
+                if not any(active):
+                    break
+                Ks = [len(b) for b in borders]
+                for c in range(k):
+                    if borders[c]:
+                        per_class[c]["border_sizes"].append(Ks[c])
+                        per_class[c]["degrees"].append(d)
 
-            # shared capacity: regrow when the largest class overflows
-            while max(ells[c] + Ks[c] for c in range(k)) > Lcap:
-                A = jnp.pad(A, ((0, 0), (0, 0), (0, Lcap)))  # keeps A's sharding
-                Lcap *= 2
-                scope.regrowth(Lcap)
-                state = ihb_mod.grow_state(state, Lcap)
-                if mesh is not None:
-                    A = jax.device_put(A, bspec)
-                    state = jax.device_put(state, rep)
-            Kcap = max(config.cap_border, pow2_bucket(max(Ks)))
-            parents = np.zeros((k, Kcap), np.int32)
-            vars_ = np.zeros((k, Kcap), np.int32)
-            valid = np.zeros((k, Kcap), bool)  # done classes: all-False -> no-op
-            for c in range(k):
-                if borders[c]:
-                    parents[c], vars_[c], valid[c] = border_index_arrays(
-                        books[c], borders[c], Kcap
-                    )
+                # shared capacity: regrow when the largest class overflows
+                while max(ells[c] + Ks[c] for c in range(k)) > Lcap:
+                    A = jnp.pad(A, ((0, 0), (0, 0), (0, Lcap)))  # keeps A's sharding
+                    Lcap *= 2
+                    scope.regrowth(Lcap)
+                    state = ihb_mod.grow_state(state, Lcap)
+                    if mesh is not None:
+                        A = jax.device_put(A, bspec)
+                        state = jax.device_put(state, rep)
+                Kcap = max(config.cap_border, pow2_bucket(max(Ks)))
+                parents = np.zeros((k, Kcap), np.int32)
+                vars_ = np.zeros((k, Kcap), np.int32)
+                valid = np.zeros((k, Kcap), bool)  # done classes: all-False -> no-op
+                for c in range(k):
+                    if borders[c]:
+                        parents[c], vars_[c], valid[c] = border_index_arrays(
+                            books[c], borders[c], Kcap
+                        )
 
-            ells_d = jnp.asarray(ells, jnp.int32)
-            parents_d = jnp.asarray(parents)
-            vars_d = jnp.asarray(vars_)
-            valid_d = jnp.asarray(valid)
+                ells_d = jnp.asarray(ells, jnp.int32)
+                parents_d = jnp.asarray(parents)
+                vars_d = jnp.asarray(vars_)
+                valid_d = jnp.asarray(valid)
 
             with scope.degree(d, K=int(max(Ks)), k=k):
                 # Escalation loop: the batched step donates nothing, so on an
@@ -337,10 +339,6 @@ def fit_classes(
                         A, Xd, state, ells_d, parents_d, vars_d, valid_d, m_total
                     )
                     scope.note_signature(entry.seen, sig)
-                    # cost capture rides the cold path: this degree window
-                    # already absorbs the jit compile for a new signature
-                    # (see FitScope docstring), lowering is a fraction of it
-                    scope.step_cost(entry.fn, sig, step_args)
                     A_next, st = entry.fn(*step_args)
                     # one host sync per degree: the escalation verdict rides
                     # the same transfer as the accept/reject results
@@ -356,13 +354,15 @@ def fit_classes(
                 A = A_next
                 state = st.ihb
 
-            for c in range(k):
-                if not borders[c]:
-                    continue
-                per_class[c]["solver_iters"].append(int(iters[c, : Ks[c]].sum()))
-                ells[c] = collect_degree(
-                    books[c], borders[c], accepted[c], mses[c], coeffs[c], generators[c]
-                )
+            with obs.span("fit/collect"):
+                for c in range(k):
+                    if not borders[c]:
+                        continue
+                    per_class[c]["solver_iters"].append(int(iters[c, : Ks[c]].sum()))
+                    ells[c] = collect_degree(
+                        books[c], borders[c], accepted[c], mses[c], coeffs[c],
+                        generators[c],
+                    )
 
         batch["solver_schedule_len"] = schedule
         # publish the solver-discipline outcome so obs_report can diagnose
@@ -383,9 +383,6 @@ def fit_classes(
             stats["recompiles"] = batch["recompiles"]
             stats["regrowths"] = batch["regrowths"]
             stats["degree_times"] = list(batch["degree_times"])
-            # one dispatch serves all classes: device cost is per batch, not
-            # per class (escalation re-runs append their own entries)
-            stats["flops_per_degree"] = list(batch.get("flops_per_degree", []))
             stats["solver_schedule_len"] = schedule
             stats["solver_escalations"] = batch["solver_escalations"]
             stats["class_batch"] = {

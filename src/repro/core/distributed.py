@@ -274,7 +274,6 @@ def fit(
             )
             sig = (m_pad, n, Lcap, Kcap, str(dtype))
             scope.note_signature(entry.seen, sig)
-            scope.step_cost(entry.fn, sig, step_args)
 
             with scope.degree(d, K=K):
                 A, st = entry.fn(*step_args)

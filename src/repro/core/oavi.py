@@ -753,13 +753,8 @@ def init_fit_stats(m: int, n: int, **extra) -> Dict:
         # escalate it; None/0 on paths using the while_loop refs.
         "solver_schedule_len": None,
         "solver_escalations": 0,
-        # device-level accounting (repro.obs.device): HLO flop estimate per
-        # degree step (None entries when capture is off/unavailable), XLA
-        # backend-compile seconds attributed to this fit, and the realized
-        # FLOP rate over the degree-step time.
-        "flops_per_degree": [],
+        # XLA backend-compile seconds attributed to this fit (repro.obs.device)
         "compile_seconds": 0.0,
-        "achieved_gflops": None,
         "time_total": 0.0,
         "m": m,
         "n": n,
@@ -839,7 +834,6 @@ class FitScope:
         self._t_last_degree_end: Optional[float] = None
         self._time_degrees = 0.0
         self._timing: Optional[Dict] = None
-        self._flops = 0.0
         # XLA compile attribution window: always-on (reading the listener's
         # accumulator never touches numerics or the device)
         self._compile0 = obs.device.compile_snapshot()
@@ -882,26 +876,6 @@ class FitScope:
         obs.registry().counter("fit.regrowths", backend=self.backend).inc()
         obs.event("fit/regrowth", backend=self.backend, Lcap=int(Lcap))
 
-    def step_cost(self, fn, sig, args) -> None:
-        """Record the degree step's HLO flop estimate for this signature.
-
-        Call *between* :meth:`note_signature` and the :meth:`degree` window:
-        the one-time lowering cost per new signature then lands in
-        ``time_unattributed``, keeping ``degree_times`` pure device+sync
-        time.  Appends to ``stats["flops_per_degree"]`` (None when capture
-        is off) so the list stays aligned with ``stats["degrees"]``.
-        """
-        cost = obs.device.step_cost(fn, sig, args)
-        self.record_flops(None if cost is None else cost["flops"])
-
-    def record_flops(self, flops: Optional[float]) -> None:
-        """Append one degree's flop estimate (None = capture unavailable).
-        Composite paths (streaming: accumulator x chunks + stats step) sum
-        their components and record through this."""
-        self.stats.setdefault("flops_per_degree", []).append(flops)
-        if flops:
-            self._flops += flops
-
     def timing_fields(self) -> Dict:
         """The timing-contract fields, computed once (shared by every class
         of a batched fit so their stats agree to the bit)."""
@@ -938,12 +912,6 @@ class FitScope:
         s1, c1 = obs.device.compile_snapshot()
         stats["compile_seconds"] = round(s1 - self._compile0[0], 6)
         stats["xla_compiles"] = c1 - self._compile0[1]
-        degrees_t = self._timing["time_degrees"] if self._timing else 0.0
-        if self._flops > 0.0 and degrees_t > 0.0:
-            stats["achieved_gflops"] = round(self._flops / degrees_t / 1e9, 3)
-            obs.registry().gauge(
-                "device.achieved_gflops", backend=self.backend
-            ).set(stats["achieved_gflops"])
         stats["num_G"] = len(generators)
         stats["num_O"] = len(book)
         stats["G_plus_O"] = len(generators) + len(book)
@@ -999,25 +967,26 @@ def fit(
     stats = init_fit_stats(m, n)
 
     with FitScope(stats, backend="local") as scope:
-        perm = None
-        if config.ordering in ("pearson", "reverse_pearson"):
-            perm = pearson_order(X, reverse=(config.ordering == "reverse_pearson"))
-            X = X[:, perm]
+        with obs.span("fit/prepare"):
+            perm = None
+            if config.ordering in ("pearson", "reverse_pearson"):
+                perm = pearson_order(X, reverse=(config.ordering == "reverse_pearson"))
+                X = X[:, perm]
 
-        Xd = jnp.asarray(X, dtype)
-        book = terms_mod.TermBook(n=n)
-        generators: List[Generator] = []
+            Xd = jnp.asarray(X, dtype)
+            book = terms_mod.TermBook(n=n)
+            generators: List[Generator] = []
 
-        Lcap = pow2_bucket(config.cap_terms)
-        A = jnp.zeros((m, Lcap), dtype).at[:, 0].set(1.0)
-        # normalized Gram convention: AtA[0,0] = ||1||^2 / m = 1
-        state = ihb_mod.init_state(
-            Lcap, jnp.asarray(1.0, dtype), dtype, factors=config.ihb_factors()
-        )
-        ell = 1
+            Lcap = pow2_bucket(config.cap_terms)
+            A = jnp.zeros((m, Lcap), dtype).at[:, 0].set(1.0)
+            # normalized Gram convention: AtA[0,0] = ||1||^2 / m = 1
+            state = ihb_mod.init_state(
+                Lcap, jnp.asarray(1.0, dtype), dtype, factors=config.ihb_factors()
+            )
+            ell = 1
 
-        entry = degree_step_entry(config, factory=_degree_step_factory)
-        m_total = jnp.asarray(float(m), dtype)
+            entry = degree_step_entry(config, factory=_degree_step_factory)
+            m_total = jnp.asarray(float(m), dtype)
 
         d = 0
         while True:
@@ -1025,39 +994,39 @@ def fit(
             if d > config.max_degree:
                 stats["termination"] = f"max_degree={config.max_degree}"
                 break
-            border = book.border(d)
-            if not border:
-                stats["termination"] = "empty_border"
-                break
-            K = len(border)
-            stats["border_sizes"].append(K)
-            stats["degrees"].append(d)
+            with obs.span("fit/border"):
+                border = book.border(d)
+                if not border:
+                    stats["termination"] = "empty_border"
+                    break
+                K = len(border)
+                stats["border_sizes"].append(K)
+                stats["degrees"].append(d)
 
-            # capacity management: device-side regrowth into the next pow2 bucket
-            while ell + K > Lcap:
-                Lcap *= 2
-                scope.regrowth(Lcap)
-                A = jax.lax.dynamic_update_slice(
-                    jnp.zeros((m, Lcap), dtype), A, (0, 0)
+                # capacity management: device-side regrowth into the next pow2 bucket
+                while ell + K > Lcap:
+                    Lcap *= 2
+                    scope.regrowth(Lcap)
+                    A = jax.lax.dynamic_update_slice(
+                        jnp.zeros((m, Lcap), dtype), A, (0, 0)
+                    )
+                    state = ihb_mod.grow_state(state, Lcap)
+
+                Kcap = max(config.cap_border, pow2_bucket(K))
+                parents, vars_, valid = border_index_arrays(book, border, Kcap)
+
+                step_args = (
+                    A,
+                    Xd,
+                    state,
+                    jnp.asarray(ell, jnp.int32),
+                    jnp.asarray(parents),
+                    jnp.asarray(vars_),
+                    jnp.asarray(valid),
+                    m_total,
                 )
-                state = ihb_mod.grow_state(state, Lcap)
-
-            Kcap = max(config.cap_border, pow2_bucket(K))
-            parents, vars_, valid = border_index_arrays(book, border, Kcap)
-
-            step_args = (
-                A,
-                Xd,
-                state,
-                jnp.asarray(ell, jnp.int32),
-                jnp.asarray(parents),
-                jnp.asarray(vars_),
-                jnp.asarray(valid),
-                m_total,
-            )
-            sig = (m, n, Lcap, Kcap, str(dtype))
-            scope.note_signature(entry.seen, sig)
-            scope.step_cost(entry.fn, sig, step_args)
+                sig = (m, n, Lcap, Kcap, str(dtype))
+                scope.note_signature(entry.seen, sig)
 
             with scope.degree(d, K=K):
                 A, st = entry.fn(*step_args)
@@ -1066,9 +1035,10 @@ def fit(
                 mses = np.asarray(st.mses)
                 coeffs = np.asarray(st.coeffs)
                 iters = np.asarray(st.iters)
-            stats["solver_iters"].append(int(iters[:K].sum()))
 
-            ell = collect_degree(book, border, accepted, mses, coeffs, generators)
+            with obs.span("fit/collect"):
+                stats["solver_iters"].append(int(iters[:K].sum()))
+                ell = collect_degree(book, border, accepted, mses, coeffs, generators)
 
         scope.finalize(book, generators, Lcap, config)
     return OAVIModel(

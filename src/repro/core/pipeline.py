@@ -29,6 +29,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import obs
 from .svm import LinearSVM, LinearSVMConfig
 from .transform import MinMaxScaler
 
@@ -198,43 +199,51 @@ class VanishingIdealClassifier:
     def fit(self, X, y) -> "VanishingIdealClassifier":
         from .. import api
 
-        t0 = time.perf_counter()
-        # an engine attached to a previous fit's models would be silently
-        # bypassed by matches() on every call while pinning the old model
-        # set and its compiled buckets — drop it; re-attach_engine() after
-        self.engine = None
-        X = self.scaler.fit_transform(X)
-        y = np.asarray(y)
-        self.classes_ = np.unique(y)
-        self.models = self._fit_generator_models([X[y == c] for c in self.classes_])
-        gen_stats = [m.stats for m in self.models]
-        t_gen = time.perf_counter() - t0
-        t1 = time.perf_counter()
-        Xt = self._feature_transform(X)
-        t_transform = time.perf_counter() - t1
-        t2 = time.perf_counter()
-        self.svm.fit(Xt, y)
-        t_svm = time.perf_counter() - t2
-        # recompiles/regrowths: class-batched groups share one compile
-        # schedule — aggregate once per group, not once per class
-        agg = api.aggregate_fit_stats(self.models)
-        self.stats = {
-            "time_generators": t_gen,
-            "time_transform": t_transform,
-            "time_svm": t_svm,
-            "time_total": time.perf_counter() - t0,
-            "num_features": Xt.shape[1],
-            "G_plus_O": sum(s.get("G_plus_O", 0) for s in gen_stats),
-            "recompiles": agg["recompiles"],
-            "regrowths": agg["regrowths"],
-            "class_batched": agg["class_batched"],
-            "solver_schedule_len": agg["solver_schedule_len"],
-            "solver_escalations": agg["solver_escalations"],
-            "per_class": gen_stats,
-            "svm": self.svm.stats,
-        }
-        if "class_batch_padding" in agg:
-            self.stats["class_batch_padding"] = agg["class_batch_padding"]
+        # each span wraps the statements its ``stats`` time covers
+        with obs.span("pipeline/fit"):
+            t0 = time.perf_counter()
+            # an engine attached to a previous fit's models would be silently
+            # bypassed by matches() on every call while pinning the old model
+            # set and its compiled buckets — drop it; re-attach_engine() after
+            self.engine = None
+            with obs.span("pipeline/scale"):
+                X = self.scaler.fit_transform(X)
+            y = np.asarray(y)
+            self.classes_ = np.unique(y)
+            with obs.span("pipeline/generators"):
+                self.models = self._fit_generator_models(
+                    [X[y == c] for c in self.classes_]
+                )
+            gen_stats = [m.stats for m in self.models]
+            t_gen = time.perf_counter() - t0
+            with obs.span("pipeline/transform"):
+                t1 = time.perf_counter()
+                Xt = self._feature_transform(X)
+                t_transform = time.perf_counter() - t1
+            with obs.span("pipeline/svm"):
+                t2 = time.perf_counter()
+                self.svm.fit(Xt, y)
+                t_svm = time.perf_counter() - t2
+            # recompiles/regrowths: class-batched groups share one compile
+            # schedule — aggregate once per group, not once per class
+            agg = api.aggregate_fit_stats(self.models)
+            self.stats = {
+                "time_generators": t_gen,
+                "time_transform": t_transform,
+                "time_svm": t_svm,
+                "time_total": time.perf_counter() - t0,
+                "num_features": Xt.shape[1],
+                "G_plus_O": sum(s.get("G_plus_O", 0) for s in gen_stats),
+                "recompiles": agg["recompiles"],
+                "regrowths": agg["regrowths"],
+                "class_batched": agg["class_batched"],
+                "solver_schedule_len": agg["solver_schedule_len"],
+                "solver_escalations": agg["solver_escalations"],
+                "per_class": gen_stats,
+                "svm": self.svm.stats,
+            }
+            if "class_batch_padding" in agg:
+                self.stats["class_batch_padding"] = agg["class_batch_padding"]
         return self
 
     def transform(self, X) -> np.ndarray:

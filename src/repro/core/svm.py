@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
+
 
 # ---------------------------------------------------------------------------
 # Linear l1 squared-hinge SVM (FISTA)
@@ -99,32 +101,35 @@ class LinearSVM:
 
     def fit(self, X, y) -> "LinearSVM":
         dt = jnp.dtype(self.config.dtype)
-        X = jnp.asarray(np.asarray(X), dt)
         y = np.asarray(y)
         self.classes_ = np.unique(y)
-        Y = np.where(y[:, None] == self.classes_[None, :], 1.0, -1.0)
-        Y = jnp.asarray(Y, dt)
-        # Lipschitz constant of the squared-hinge gradient: 2/m * lmax(X~^T X~)
-        m = X.shape[0]
-        Xb = jnp.concatenate([X, jnp.ones((m, 1), dt)], axis=1)
-        # power iteration for the top singular value
-        v = jnp.ones((Xb.shape[1],), dt)
-        hi = jax.lax.Precision.HIGHEST  # f32 matvecs, also on TPU
+        with obs.span("svm/prepare"):
+            X = jnp.asarray(np.asarray(X), dt)
+            Y = np.where(y[:, None] == self.classes_[None, :], 1.0, -1.0)
+            Y = jnp.asarray(Y, dt)
+            # Lipschitz constant of the squared-hinge gradient: 2/m * lmax(X~^T X~)
+            m = X.shape[0]
+            Xb = jnp.concatenate([X, jnp.ones((m, 1), dt)], axis=1)
+            # power iteration for the top singular value
+            v = jnp.ones((Xb.shape[1],), dt)
+            hi = jax.lax.Precision.HIGHEST  # f32 matvecs, also on TPU
 
-        def gram_v(v):
-            return jnp.matmul(Xb.T, jnp.matmul(Xb, v, precision=hi), precision=hi)
+            def gram_v(v):
+                return jnp.matmul(Xb.T, jnp.matmul(Xb, v, precision=hi), precision=hi)
 
-        for _ in range(20):
-            v = gram_v(v)
-            v = v / jnp.maximum(jnp.linalg.norm(v), 1e-30)
-        lmax = jnp.dot(v, gram_v(v), precision=hi)
-        step = 1.0 / jnp.maximum(2.0 * lmax / m, 1e-12)
-        W, b, iters = _fista(
-            X, Y, jnp.asarray(self.config.lam, dt), step,
-            self.config.max_iter, jnp.asarray(self.config.tol, dt),
-        )
-        self.W, self.b = np.asarray(W), np.asarray(b)
-        self.stats = {"iters": int(iters), "nnz": int((np.abs(self.W) > 0).sum())}
+            for _ in range(20):
+                v = gram_v(v)
+                v = v / jnp.maximum(jnp.linalg.norm(v), 1e-30)
+            lmax = jnp.dot(v, gram_v(v), precision=hi)
+            step = 1.0 / jnp.maximum(2.0 * lmax / m, 1e-12)
+        with obs.span("svm/loop"):
+            W, b, iters = _fista(
+                X, Y, jnp.asarray(self.config.lam, dt), step,
+                self.config.max_iter, jnp.asarray(self.config.tol, dt),
+            )
+            self.W, self.b = np.asarray(W), np.asarray(b)
+            iters = int(iters)
+        self.stats = {"iters": iters, "nnz": int((np.abs(self.W) > 0).sum())}
         return self
 
     def decision_function(self, X) -> np.ndarray:

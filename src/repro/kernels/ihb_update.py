@@ -86,6 +86,7 @@ def ihb_update(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((L, L), N.dtype),
         interpret=interpret,
+        name="ihb_update",  # the Mosaic kernel's name and a scope in the op metadata
     )(
         ell.astype(jnp.int32).reshape(1),
         s.astype(N.dtype).reshape(1, 1),

@@ -16,9 +16,11 @@ Env toggles (read once at import, overridable via :func:`configure`):
 - ``OBS_TRACE_EVENTS`` default 100000 — trace ring-buffer capacity.
 - ``OBS_SAMPLE_EVERY`` default 1 — keep every Nth span per span name
   (deterministic counter-based sampling, no randomness).
-- ``OBS_JAX_TRACE``    default 0 — additionally wrap each span in
-  ``jax.profiler.TraceAnnotation`` so obs spans line up with XLA timelines
-  when a jax profile is being captured.
+
+Every span also enters a ``jax.profiler.TraceAnnotation`` of its
+name, so an open profiler session writes the program's spans on its host
+plane, on the same clock as the device's operations.  With no session open
+the annotation adds about half a microsecond to a span.
 """
 
 from __future__ import annotations
@@ -31,6 +33,11 @@ from typing import Dict, List, Optional
 
 from .metrics import Registry
 from .trace import TraceBuffer, chrome_trace, export_chrome_trace
+
+try:  # obs imports without jax (e.g. standalone tooling): spans stay local
+    from jax.profiler import TraceAnnotation
+except ImportError:  # pragma: no cover
+    TraceAnnotation = None
 
 __all__ = [
     "span", "event", "counter_event", "enabled", "enable", "disable",
@@ -50,7 +57,6 @@ class _State:
     def __init__(self) -> None:
         self.enabled = _env_int("OBS_ENABLED", 1) != 0
         self.sample_every = max(1, _env_int("OBS_SAMPLE_EVERY", 1))
-        self.jax_trace = _env_int("OBS_JAX_TRACE", 0) != 0
         self.buffer = TraceBuffer(maxlen=max(16, _env_int("OBS_TRACE_EVENTS", 100_000)))
         self.registry = Registry()
         self.epoch = time.perf_counter()
@@ -74,14 +80,6 @@ _STATE = _State()
 _LOCAL = threading.local()
 
 
-def _jax_annotation(name: str):
-    try:  # deferred so obs imports without jax (e.g. standalone tooling)
-        from jax.profiler import TraceAnnotation
-    except Exception:
-        return None
-    return TraceAnnotation(name)
-
-
 class Span:
     """A recorded span.  Use via ``with obs.span("fit/degree", d=3): ...``."""
 
@@ -99,10 +97,9 @@ class Span:
         if stack is None:
             stack = _LOCAL.stack = []
         stack.append(self.name)
-        if _STATE.jax_trace:
-            self._jax_ctx = _jax_annotation(self.name)
-            if self._jax_ctx is not None:
-                self._jax_ctx.__enter__()
+        if TraceAnnotation is not None:
+            self._jax_ctx = TraceAnnotation(self.name)
+            self._jax_ctx.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -197,15 +194,12 @@ class disabled:
 
 def configure(enabled: Optional[bool] = None,
               sample_every: Optional[int] = None,
-              jax_trace: Optional[bool] = None,
               trace_capacity: Optional[int] = None) -> None:
     """Override env-derived settings at runtime."""
     if enabled is not None:
         _STATE.enabled = enabled
     if sample_every is not None:
         _STATE.sample_every = max(1, int(sample_every))
-    if jax_trace is not None:
-        _STATE.jax_trace = jax_trace
     if trace_capacity is not None:
         _STATE.buffer = TraceBuffer(maxlen=max(16, int(trace_capacity)))
 

@@ -1,27 +1,19 @@
-"""Device-level observability: compile time, HLO cost, memory, profiles.
+"""Device-level observability: compile time, memory, profiles.
 
-Four capabilities, all best-effort and all safe without jax installed:
+Three capabilities, all best-effort and all safe without jax installed:
 
 - **XLA compile accounting** — a process-global listener on jax's internal
   event-duration channel accumulates ``backend_compile`` seconds, and
   :class:`CompileWindow` attributes the delta over a code region (a fit, an
   engine warmup).  This measures the *actual* XLA compile, not the Python
   call that happened to trigger it.
-- **Per-step HLO cost analysis** — :func:`step_cost` lowers a jitted
-  callable for one argument signature and reads ``cost_analysis()``
-  (flops / bytes accessed / output bytes).  Lowering traces but does not
-  XLA-compile, so the capture is a one-time host cost per signature, cached
-  alongside the degree-step cache's own signature set — warm steps pay a
-  dict lookup, cold steps pay one extra trace on a path that is about to
-  compile anyway.
 - **Live-memory timeline** — :func:`sample_memory` unifies the allocator
   high-water mark (TPU/GPU) and live-array accounting (CPU) into one
   sampling point that updates fit-stats peaks, sets registry gauges, and
   emits a Chrome counter event so traces show memory over time.
 - **Profiler windows** — :func:`profile_window` opens a ``jax.profiler``
   trace when ``OBS_JAX_PROFILE=<dir>`` is set, so XLA device timelines
-  interleave with obs spans (which already carry ``TraceAnnotation`` under
-  ``OBS_JAX_TRACE=1``).
+  interleave with obs spans (each span enters a ``TraceAnnotation``).
 
 Gating: everything here is additionally gated by ``OBS_DEVICE`` (default
 on) AND :func:`repro.obs.enabled` — ``obs.disabled()`` therefore yields the
@@ -33,8 +25,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
-from collections import OrderedDict
 from typing import Dict, Optional, Tuple
 
 from .core import counter_event, enabled, event, registry
@@ -43,12 +33,10 @@ __all__ = [
     "CompileWindow",
     "compile_snapshot",
     "device_enabled",
-    "capture_stats",
     "device_memory_stats",
     "live_buffer_bytes",
     "profile_window",
     "sample_memory",
-    "step_cost",
 ]
 
 _BACKEND_COMPILE_SUFFIX = "backend_compile_duration"
@@ -127,72 +115,6 @@ class CompileWindow:
         s1, c1 = compile_snapshot()
         self.seconds = s1 - self._s0
         self.count = c1 - self._c0
-
-
-# ---------------------------------------------------------------------------
-# Per-step HLO cost analysis
-# ---------------------------------------------------------------------------
-
-_COST_LOCK = threading.Lock()
-_COST_CACHE: "OrderedDict[Tuple, Optional[Dict]]" = OrderedDict()
-_COST_CACHE_CAP = 512
-_CAPTURE = {"captures": 0, "failures": 0, "seconds": 0.0}
-
-
-def capture_stats() -> Dict:
-    """Cost-capture telemetry: captures, failures, cumulative capture time."""
-    with _COST_LOCK:
-        return dict(_CAPTURE)
-
-
-def _capture_cost(fn, args, kwargs) -> Optional[Dict]:
-    t0 = time.perf_counter()
-    try:
-        analysis = fn.lower(*args, **kwargs).cost_analysis()
-    except Exception:
-        with _COST_LOCK:
-            _CAPTURE["failures"] += 1
-        return None
-    if isinstance(analysis, (list, tuple)):  # some backends: one per device
-        analysis = analysis[0] if analysis else {}
-    if not isinstance(analysis, dict):
-        analysis = {}
-    dt = time.perf_counter() - t0
-    cost = {
-        "flops": float(analysis.get("flops", 0.0) or 0.0),
-        "bytes_accessed": float(analysis.get("bytes accessed", 0.0) or 0.0),
-        "bytes_out": float(analysis.get("bytes accessedout{}", 0.0) or 0.0),
-        "capture_s": round(dt, 6),
-    }
-    with _COST_LOCK:
-        _CAPTURE["captures"] += 1
-        _CAPTURE["seconds"] += dt
-    registry().histogram("device.cost_capture_seconds").observe(dt)
-    event("device/cost_capture", flops=cost["flops"],
-          bytes_accessed=cost["bytes_accessed"], capture_s=cost["capture_s"])
-    return cost
-
-
-def step_cost(fn, sig, args, kwargs: Optional[dict] = None) -> Optional[Dict]:
-    """HLO cost estimate for jitted ``fn`` at one argument signature.
-
-    Returns ``{"flops", "bytes_accessed", "bytes_out", "capture_s"}`` or
-    None (capture off, or the backend exposes no cost model).  ``sig`` must
-    identify the trace signature the caller would use for compile counting —
-    the result is cached per ``(fn, sig)`` so repeat calls are a dict hit.
-    """
-    if not device_enabled():
-        return None
-    key = (id(fn), sig)
-    with _COST_LOCK:
-        if key in _COST_CACHE:
-            return _COST_CACHE[key]
-    cost = _capture_cost(fn, args, kwargs or {})
-    with _COST_LOCK:
-        _COST_CACHE[key] = cost
-        while len(_COST_CACHE) > _COST_CACHE_CAP:
-            _COST_CACHE.popitem(last=False)
-    return cost
 
 
 # ---------------------------------------------------------------------------

@@ -135,21 +135,13 @@ class TransformEngine:
         self._slo = obs.registry().histogram(
             "serve.transform_seconds", backend=self.backend
         )
-        # device-level accounting: HLO flop estimate per bucket (captured
-        # once per bucket via lowering, no XLA compile), cumulative flops
-        # actually dispatched, and XLA backend-compile seconds attributed to
-        # this engine's warmup/first-call compiles
-        self._bucket_flops: Dict[int, Optional[float]] = {}
-        self._flops_dispatched = 0.0
+        # XLA backend-compile seconds attributed to this engine's
+        # warmup/first-call compiles
         self._compile_seconds = 0.0
 
     @property
     def stats(self) -> Dict:
         """Point-in-time counter view (same keys as the historical dict)."""
-        lat = self.latency.summary()
-        achieved = None
-        if self._flops_dispatched > 0.0 and lat["sum"] > 0.0:
-            achieved = round(self._flops_dispatched / lat["sum"] / 1e9, 3)
         return {
             "requests": self._requests.value,
             "rows": self._rows.value,
@@ -158,11 +150,8 @@ class TransformEngine:
             "recompiles": self._recompiles.value,
             "warmup_compiles": self._warmup_compiles.value,
             "buckets": {b: c.value for b, c in sorted(self._bucket_calls.items())},
-            "latency": lat,
-            "flops_per_bucket": dict(sorted(self._bucket_flops.items())),
-            "flops_dispatched": self._flops_dispatched,
+            "latency": self.latency.summary(),
             "compile_seconds": round(self._compile_seconds, 6),
-            "achieved_gflops": achieved,
         }
 
     # -- plan / shape machinery -------------------------------------------
@@ -210,21 +199,6 @@ class TransformEngine:
 
     # -- execution ---------------------------------------------------------
 
-    def _bucket_cost(self, b: int) -> Optional[float]:
-        """Flop estimate of one ``b``-row device call (HLO cost analysis,
-        captured once per bucket — lowering traces without XLA-compiling)."""
-        if not obs.device.device_enabled():
-            return None
-        with self._lock:
-            if b in self._bucket_flops:
-                return self._bucket_flops[b]
-        aval = jax.ShapeDtypeStruct((b, self.consts.n), self.plan.dtype)
-        cost = obs.device.step_cost(self._fn, ("serve", b), (aval,))
-        flops = None if cost is None else cost["flops"]
-        with self._lock:
-            self._bucket_flops.setdefault(b, flops)
-        return flops
-
     def warmup(self, max_rows: Optional[int] = None) -> int:
         """Trace-and-compile every bucket up to ``max_rows`` (default: all).
 
@@ -241,7 +215,6 @@ class TransformEngine:
                     if b in self._seen_buckets:
                         continue
                     self._seen_buckets.add(b)
-                self._bucket_cost(b)
                 Zb = np.zeros((b, self.consts.n), self.plan.dtype)
                 with obs.span(
                     "serve/warmup_compile", bucket=b, backend=self.backend
@@ -268,10 +241,6 @@ class TransformEngine:
                 bucket = self._bucket_calls.setdefault(b, obs.Counter())
         self._device_calls.inc()
         bucket.inc()
-        flops = self._bucket_cost(b)
-        if flops:
-            with self._lock:
-                self._flops_dispatched += flops
         if not fresh:
             return np.asarray(self._fn(jnp.asarray(Zp)))
         # cold bucket outside warmup: attribute the XLA compile to the engine

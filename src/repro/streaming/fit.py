@@ -467,48 +467,7 @@ def fit(
             sig = (Lcap, Kcap, str(dtype))
             scope.note_signature(entry.seen, sig)
 
-            # HLO cost of the degree = accumulator flops x chunk count plus
-            # the stats step, lowered from abstract shapes (the real buffers
-            # only exist inside the degree window).  The accumulator re-lowers
-            # each degree because its jitted fn is book-specific — the same
-            # degree already pays a full jit trace + compile for it, so the
-            # extra lowering rides an inherently cold path.
             sample_chunks = obs.device.device_enabled()
-            if sample_chunks:
-                aval = jax.ShapeDtypeStruct
-                f32 = jnp.float32
-                rows_cap = shards * chunk_rows if mesh is not None else chunk_rows
-                if mesh is None:
-                    acc_shapes = ((Lcap, Kcap), (Kcap, Kcap))
-                else:
-                    acc_shapes = ((shards, Lcap, Kcap), (shards, Kcap, Kcap))
-                idx_aval = aval((Kcap,), jnp.int32)
-                acc_avals = (
-                    aval(acc_shapes[0], f32), aval(acc_shapes[1], f32),
-                    aval((rows_cap, n), dtype), aval((rows_cap,), dtype),
-                    idx_aval, idx_aval,
-                )
-                state_avals = jax.tree_util.tree_map(
-                    lambda x: aval(jnp.shape(x), x.dtype), state
-                )
-                step_avals = (
-                    aval(acc_shapes[0], f32), aval(acc_shapes[1], f32),
-                    state_avals, aval((), jnp.int32),
-                    aval((Kcap,), jnp.bool_), aval((), dtype),
-                )
-                acc_cost = obs.device.step_cost(
-                    acc_fn, ("acc", len(book), shards) + acc_sig, acc_avals
-                )
-                st_cost = obs.device.step_cost(entry.fn, sig, step_avals)
-                flops = None
-                if acc_cost is not None or st_cost is not None:
-                    flops = (
-                        (acc_cost["flops"] if acc_cost else 0.0) * steps_per_pass
-                        + (st_cost["flops"] if st_cost else 0.0)
-                    )
-                scope.record_flops(flops)
-            else:
-                scope.record_flops(None)
 
             with scope.degree(d, K=K):
                 parents_d = jnp.asarray(parents)
@@ -782,7 +741,6 @@ def fit_classes(
                     csig = (k, Lcap, Kcap, str(dtype), schedule)
                     cargs = (accQL_b, accC_b, state, ells_d, valid_d, m_total)
                     scope.note_signature(entry.seen, csig)
-                    scope.step_cost(entry.fn, csig, cargs)
                     st = entry.fn(*cargs)
                     if schedule is None or not bool(
                         np.any(jax.device_get(st.unconverged))
@@ -821,7 +779,6 @@ def fit_classes(
             stats["recompiles"] = batch["recompiles"]
             stats["regrowths"] = batch["regrowths"]
             stats["degree_times"] = list(batch["degree_times"])
-            stats["flops_per_degree"] = list(batch.get("flops_per_degree", []))
             stats["solver_schedule_len"] = schedule
             stats["solver_escalations"] = batch["solver_escalations"]
             stats["class_batch"] = {

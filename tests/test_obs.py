@@ -36,7 +36,7 @@ from repro.resilience import Journal, JournalError
 @pytest.fixture(autouse=True)
 def _clean_obs():
     """Each test starts from enabled, unsampled, empty recorder state."""
-    obs.configure(enabled=True, sample_every=1, jax_trace=False)
+    obs.configure(enabled=True, sample_every=1)
     obs.reset()
     yield
     obs.configure(enabled=True, sample_every=1)
