@@ -6,7 +6,9 @@ Two models, built from scratch (no scikit-learn in this container):
   trained with FISTA (accelerated proximal gradient; the l1 prox is
   soft-thresholding).  This is the paper's downstream classifier for the
   OAVI/ABM/VCA feature transforms ("l1-penalized squared hinge loss",
-  Section 6.1).
+  Section 6.1).  On a TPU each iteration's gradient is one pass of the
+  ``svm_grad`` kernel over a feature-major copy of the features, laid out
+  once per fit (``kernels/svm_grad.py``); elsewhere it is the jnp reference.
 * :class:`PolySVM` — polynomial-kernel SVM baseline with l2 regularization,
   one-vs-rest, trained in the (kernelized) primal with accelerated gradient
   descent on the dual coefficients.  Exact kernel up to ``max_kernel_samples``
@@ -28,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
+from ..kernels import ops
 
 
 # ---------------------------------------------------------------------------
@@ -43,26 +46,18 @@ class LinearSVMConfig:
     dtype: str = "float32"
 
 
-def _squared_hinge_grad(W, b, Xb, Y):
-    """Mean squared-hinge loss + gradients.  Y in {-1, +1}, shape (m, k)."""
-    m = Xb.shape[0]
-    scores = jnp.matmul(Xb, W, precision=jax.lax.Precision.HIGHEST) + b  # (m, k)
-    margin = 1.0 - Y * scores
-    active = jnp.maximum(margin, 0.0)
-    loss = jnp.mean(jnp.sum(active * active, axis=1))
-    g_scores = (-2.0 / m) * (active * Y)  # (m, k)
-    gW = jnp.matmul(Xb.T, g_scores, precision=jax.lax.Precision.HIGHEST)
-    gb = jnp.sum(g_scores, axis=0)
-    return loss, gW, gb
-
-
 def _soft_threshold(x, t):
     return jnp.sign(x) * jnp.maximum(jnp.abs(x) - t, 0.0)
 
 
-@partial(jax.jit, static_argnames=("max_iter",))
-def _fista(X, Y, lam, step, max_iter, tol):
-    p, k = X.shape[1], Y.shape[1]
+@partial(jax.jit, static_argnames=("max_iter", "m", "use_pallas", "interpret"))
+def _fista(X, Y, lam, step, max_iter, tol, *, m, use_pallas=False, interpret=False):
+    """FISTA over the ``m`` rows that ``ops.svm_grad_operands`` laid out as
+    ``(X, Y)`` with the same ``use_pallas`` and ``interpret``."""
+    if use_pallas or interpret:  # the kernel's feature-major (p, R, 128), (k, R, 128)
+        p, k = X.shape[0], Y.shape[0]
+    else:
+        p, k = X.shape[1], Y.shape[1]
     dtype = X.dtype
     W = jnp.zeros((p, k), dtype)
     b = jnp.zeros((k,), dtype)
@@ -73,7 +68,7 @@ def _fista(X, Y, lam, step, max_iter, tol):
 
     def body(state):
         W, b, Wz, bz, t, i, _ = state
-        _, gW, gb = _squared_hinge_grad(Wz, bz, X, Y)
+        gW, gb = ops.svm_grad(Wz, bz, X, Y, m, use_pallas=use_pallas, interpret=interpret)
         W_new = _soft_threshold(Wz - step * gW, step * lam)
         b_new = bz - step * gb  # bias unpenalized
         t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
@@ -122,14 +117,23 @@ class LinearSVM:
                 v = v / jnp.maximum(jnp.linalg.norm(v), 1e-30)
             lmax = jnp.dot(v, gram_v(v), precision=hi)
             step = 1.0 / jnp.maximum(2.0 * lmax / m, 1e-12)
+            fused = ops.svm_grad_picks_kernel(X.shape[1], Y.shape[1], dt)
+            Xg, Yg = ops.svm_grad_operands(X, Y, use_pallas=fused)
         with obs.span("svm/loop"):
             W, b, iters = _fista(
-                X, Y, jnp.asarray(self.config.lam, dt), step,
+                Xg, Yg, jnp.asarray(self.config.lam, dt), step,
                 self.config.max_iter, jnp.asarray(self.config.tol, dt),
+                m=m, use_pallas=fused,
             )
             self.W, self.b = np.asarray(W), np.asarray(b)
             iters = int(iters)
-        self.stats = {"iters": iters, "nnz": int((np.abs(self.W) > 0).sum())}
+        if fused:
+            obs.registry().counter("svm/fused_grad_fits").inc()
+        self.stats = {
+            "iters": iters,
+            "nnz": int((np.abs(self.W) > 0).sum()),
+            "grad_kernel": "pallas" if fused else "jnp",
+        }
         return self
 
     def decision_function(self, X) -> np.ndarray:

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
+from . import svm_grad as _svm_grad
 from .flash_attention import flash_attention as _flash_kernel
 from .gram_update import gram_update as _gram_kernel
 from .gram_update import gram_update_acc as _gram_acc_kernel
@@ -115,6 +116,35 @@ def ihb_update(N, q, btb, ell, *, u=None, use_pallas=None, interpret=False):
     # Schur complement, reduced exactly as the reference reduces it
     s = jnp.maximum(btb - jnp.sum(q * u), jnp.asarray(1e-30, N.dtype))
     return _ihb_kernel(N, u, s, jnp.asarray(ell, jnp.int32), interpret=interpret)
+
+
+def svm_grad_picks_kernel(p: int, k: int, dtype) -> bool:
+    """What ``use_pallas=None`` means in :func:`svm_grad_operands` and
+    :func:`svm_grad` for ``p`` features and ``k`` classes: the kernel on a
+    TPU, for f32 data whose accumulators fit the kernel's VMEM."""
+    return _on_tpu() and jnp.dtype(dtype) == jnp.float32 and _svm_grad.fits(p, k)
+
+
+def svm_grad_operands(X, Y, *, use_pallas=None, interpret=False):
+    """``(X, Y)`` as :func:`svm_grad` takes them, built once per fit: the
+    ``(m, p)`` features and ``(m, k)`` labels as they are for the reference;
+    for the kernel a feature-major copy, rows padded with label 0."""
+    if use_pallas is None:
+        use_pallas = svm_grad_picks_kernel(X.shape[1], Y.shape[1], X.dtype)
+    if not (use_pallas or interpret):
+        return X, Y
+    return _svm_grad.layout(X, Y)
+
+
+def svm_grad(W, b, X, Y, m: int, *, use_pallas=None, interpret=False):
+    """``(gW, gb)``: gradient of the linear SVM's mean squared hinge over
+    ``m`` rows at ``(W, b)``; ``X``, ``Y`` from :func:`svm_grad_operands`
+    with the same ``use_pallas`` and ``interpret``."""
+    if use_pallas is None:
+        use_pallas = svm_grad_picks_kernel(W.shape[0], W.shape[1], W.dtype)
+    if not (use_pallas or interpret):
+        return ref.squared_hinge_grad_ref(W, b, X, Y)
+    return _svm_grad.svm_grad(W, b, X, Y, m=m, interpret=interpret)
 
 
 def multihead_attention(

@@ -78,6 +78,20 @@ def gram_accumulate_ref(A, X, parents, vars_, ql0, c0, *, bm: int):
     return ql, c
 
 
+def squared_hinge_grad_ref(W, b, X, Y):
+    """``(gW, gb)``: gradient of the mean squared hinge of the linear SVM
+    (``core/svm.py``) at ``(W, b)``.  ``X`` is ``(m, p)``, ``Y`` is ``(m, k)``
+    with entries in {-1, +1}; the mean is over the ``m`` rows."""
+    m = X.shape[0]
+    scores = jnp.matmul(X, W, precision=_HIGHEST) + b  # (m, k)
+    margin = 1.0 - Y * scores
+    active = jnp.maximum(margin, 0.0)
+    g_scores = (-2.0 / m) * (active * Y)  # (m, k)
+    gW = jnp.matmul(X.T, g_scores, precision=_HIGHEST)
+    gb = jnp.sum(g_scores, axis=0)
+    return gW, gb
+
+
 def ihb_update_ref(N, q, btb, ell, u=None):
     """Theorem 4.9 block-inverse update on the padded inverse (identity in
     the inactive block) — mirrors :func:`repro.core.ihb.append_column`.

@@ -12,6 +12,7 @@ one file for the same reason.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +21,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import ihb as ihb_mod
 from repro.core.oavi import OAVIConfig, _make_degree_step
+from repro.core.svm import _fista
 from repro.kernels.gram_update import gram_update_acc
 from repro.kernels.ihb_update import ihb_update
+from repro.kernels.svm_grad import LANES, block_rows, svm_grad
 
 F32, I32 = jnp.float32, jnp.int32
 
@@ -110,3 +113,33 @@ def test_degree_step_with_pallas_kernels_compiles(sds):
     ).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 2, "expected the Gram and IHB kernels"
+
+
+# (m, p): the SVM features of the benchmark's cells, Appendix C and credit
+_SVM_SHAPES = [(1_200_000, 12), (18_000, 506)]
+
+
+@pytest.mark.parametrize("m,p", _SVM_SHAPES)
+def test_svm_grad_compiles(sds, m, p):
+    k = 2
+    _, R = block_rows(p, k, m)
+    compiled = jax.jit(lambda *a: svm_grad(*a, m=m)).lower(
+        sds((p, k)), sds((k,)), sds((p, R, LANES)), sds((k, R, LANES))
+    ).compile()
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("m,p", _SVM_SHAPES)
+def test_fista_with_svm_grad_compiles(sds, m, p):
+    """The whole FISTA while loop with the gradient kernel: the kernel is in
+    it, and nothing copies the feature array (XLA's jnp loop copies all of
+    it into VMEM in every iteration)."""
+    k = 2
+    _, R = block_rows(p, k, m)
+    compiled = jax.jit(
+        lambda X, Y, lam, step, tol: _fista(X, Y, lam, step, 10_000, tol, m=m, use_pallas=True)
+    ).lower(sds((p, R, LANES)), sds((k, R, LANES)), sds(()), sds(()), sds(())).compile()
+    text = compiled.as_text()
+    assert _has_kernel(compiled) and " while(" in text
+    copies_features = re.compile(re.escape(f"f32[{p},{R},{LANES}]") + r"[^=]* copy(-start)?\(")
+    assert not copies_features.search(text)

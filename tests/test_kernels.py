@@ -141,6 +141,40 @@ def test_ihb_row_block_bounds_vmem(L):
 
 
 # ---------------------------------------------------------------------------
+# svm_grad
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("m,p,k,steps", [
+    (3000, 12, 2, 1),    # Appendix C's features and classes
+    (700, 506, 2, 1),    # credit's
+    (5000, 506, 2, 3),   # credit's over several grid steps
+    (1337, 9, 3, 1),     # one-vs-rest over three classes
+])
+def test_svm_grad_vs_ref(m, p, k, steps):
+    """The kernel's (gW, gb) are the reference's to f32 rounding; the rows
+    that pad m to the block carry label 0 and add nothing."""
+    from repro.kernels.svm_grad import LANES, block_rows
+
+    rng = np.random.default_rng(m + p + k)
+    X = jnp.asarray(rng.uniform(0, 1, (m, p)), jnp.float32)
+    Y = jnp.asarray(rng.choice([-1.0, 1.0], (m, k)), jnp.float32)
+    W = jnp.asarray(rng.normal(0, 0.1, (p, k)), jnp.float32)
+    b = jnp.asarray(rng.normal(0, 0.1, k), jnp.float32)
+    Xg, Yg = ops.svm_grad_operands(X, Y, interpret=True)
+    br, R = block_rows(p, k, m)
+    assert Xg.shape == (p, R, LANES) and Yg.shape == (k, R, LANES)
+    assert R // br == steps and R * LANES > m
+    assert not np.asarray(Yg).reshape(k, -1)[:, m:].any()
+    gW, gb = ops.svm_grad(W, b, Xg, Yg, m, interpret=True)
+    gW_r, gb_r = ref.squared_hinge_grad_ref(W, b, X, Y)
+    assert gW.shape == (p, k) and gb.shape == (k,)
+    eps = 1e-5  # f32 rounding of sums over a few thousand rows
+    np.testing.assert_allclose(gW, gW_r, rtol=eps, atol=eps * np.abs(gW_r).max())
+    np.testing.assert_allclose(gb, gb_r, rtol=eps, atol=eps * np.abs(gb_r).max())
+
+
+# ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------
 

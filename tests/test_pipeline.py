@@ -94,3 +94,37 @@ def test_multiclass_one_vs_rest():
     svm = LinearSVM(LinearSVMConfig(lam=1e-4)).fit(X, y)
     assert svm.score(X, y) > 0.95
     assert set(svm.predict(X)) == {0, 1, 2}
+
+
+def test_fista_gradient_kernel_matches_jnp_path():
+    """FISTA with the svm_grad kernel (interpret mode) stops at the jnp
+    path's iteration, on its (W, b) to f32 rounding."""
+    import jax.numpy as jnp
+
+    from repro.core.svm import _fista
+    from repro.kernels import ops
+
+    rng = np.random.default_rng(4)
+    m, p, k = 700, 9, 3
+    X = jnp.asarray(rng.standard_normal((m, p)), jnp.float32)
+    y = rng.integers(0, k, m)
+    Y = jnp.asarray(np.where(y[:, None] == np.arange(k), 1.0, -1.0), jnp.float32)
+    lam, step, max_iter, tol = jnp.float32(1e-3), jnp.float32(0.05), 400, jnp.float32(1e-4)
+    W, b, iters = _fista(X, Y, lam, step, max_iter, tol, m=m)
+    Xg, Yg = ops.svm_grad_operands(X, Y, interpret=True)
+    Wk, bk, iters_k = _fista(Xg, Yg, lam, step, max_iter, tol, m=m, interpret=True)
+    assert int(iters_k) == int(iters) < max_iter
+    np.testing.assert_allclose(Wk, W, rtol=0, atol=1e-5)
+    np.testing.assert_allclose(bk, b, rtol=0, atol=1e-5)
+
+
+def test_linear_svm_reports_jnp_gradient_off_tpu():
+    from repro import obs
+
+    fused = obs.registry().counter("svm/fused_grad_fits")
+    before = fused.value
+    rng = np.random.default_rng(5)
+    X = rng.standard_normal((300, 4))
+    svm = LinearSVM(LinearSVMConfig(max_iter=50)).fit(X, (X[:, 0] > 0).astype(int))
+    assert svm.stats["grad_kernel"] == "jnp"
+    assert fused.value == before
