@@ -163,8 +163,7 @@ def compiled_degree_step(cfg, stats, n: int, mesh=None):
     mc = stats["class_batch"]["m_cap"]
     Lcap = stats["Lcap_final"]
     Kcap = max(cfg.cap_border, pow2_bucket(max(stats["border_sizes"])))
-    schedule = stats["solver_schedule_len"]
-    entry = class_batch._batched_entry(cfg, mesh, ("data",), schedule)
+    entry = class_batch._batched_entry(cfg, mesh, ("data",))
     rows = rep = None
     if mesh is not None:
         rows = NamedSharding(mesh, distributed.class_data_spec(("data",)))
@@ -220,9 +219,7 @@ def one_chip(args) -> None:
     acc = clf.score(Xte, yte)
     st = clf.stats
     log(f"fit cgavi-ihb: {t_fit:.2f}s (smoke reading) class_batched="
-        f"{st['class_batched']} recompiles={st['recompiles']} "
-        f"solver_schedule_len={st['solver_schedule_len']} "
-        f"escalations={st['solver_escalations']} accuracy={acc:.4f}")
+        f"{st['class_batched']} recompiles={st['recompiles']} accuracy={acc:.4f}")
     log(describe(clf.models))
     cfg = api.oavi_config_for("cgavi-ihb", clf.config.psi)
     kernels = kernels_in(compiled_degree_step(cfg, clf.models[0].stats, X.shape[1])[0])

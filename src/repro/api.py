@@ -519,7 +519,7 @@ def fit_classes(
     With ``class_batch="auto"`` (default) and an eligible OAVI config
     (:func:`repro.core.oavi.class_batchable`: every engine with the Theorem
     4.9 ``inverse`` — the ``fast`` closed form AND the oracle solvers/WIHB,
-    which run their masked fixed-schedule twins under ``vmap``; only the
+    which run under ``vmap`` too; only the
     Cholesky engine is excluded), classes are grouped into shared pow2 row
     buckets (:func:`repro.core.class_batch.plan_class_groups`: greedy
     buckets, cross-bucket merges while padding stays ~2x, and straggler
@@ -651,26 +651,18 @@ def aggregate_fit_stats(models: Sequence) -> Dict:
     Class-batched models share ONE compile/regrowth schedule per batch group
     (their per-model stats all carry the same counts), so naively summing
     per-class stats overcounts by the group size; this counts each group
-    once and each sequentially-fitted model individually.  The same dedup
-    applies to the solver-discipline outcome (``solver_escalations`` is per
-    batch, not per class); ``solver_schedule_len`` reports the longest
-    schedule any group ran.  ``class_batch_padding`` rolls the per-model
-    padding accounting up to dispatched/padded row totals and the overall
-    waste fraction, and the aggregate is mirrored into the metric registry
-    (``fit.solver_*`` / ``fit.class_batch_padding_waste`` with
-    ``backend="aggregate"``) so obs_report sees the classifier-level view."""
+    once and each sequentially-fitted model individually.
+    ``class_batch_padding`` rolls the per-model padding accounting up to
+    dispatched/padded row totals and the overall waste fraction, mirrored
+    into the metric registry (``fit.class_batch_padding_waste``) so
+    obs_report sees the classifier-level view."""
     recompiles = regrowths = 0
-    escalations = 0
-    schedule_len: Optional[int] = None
     batched = 0
     groups = set()
     pad_groups = set()
     dispatched_rows = padded_rows = 0
     for model in models:
         stats = getattr(model, "stats", None) or {}
-        sched = stats.get("solver_schedule_len")
-        if sched is not None:
-            schedule_len = max(int(sched), schedule_len or 0)
         group = stats.get("class_batch")
         padding = stats.get("class_batch_padding")
         if padding is not None:
@@ -690,18 +682,14 @@ def aggregate_fit_stats(models: Sequence) -> Dict:
             groups.add(group["group"])
             recompiles += int(group["recompiles"])
             regrowths += int(group["regrowths"])
-            escalations += int(stats.get("solver_escalations", 0))
         else:
             recompiles += int(stats.get("recompiles", 0))
             regrowths += int(stats.get("regrowths", 0))
-            escalations += int(stats.get("solver_escalations", 0))
     out: Dict = {
         "recompiles": recompiles,
         "regrowths": regrowths,
         "class_batched": batched,
         "class_batch_groups": len(groups),
-        "solver_schedule_len": schedule_len,
-        "solver_escalations": escalations,
     }
     if dispatched_rows:
         out["class_batch_padding"] = {
@@ -709,20 +697,10 @@ def aggregate_fit_stats(models: Sequence) -> Dict:
             "padded_rows": padded_rows,
             "waste": padded_rows / float(dispatched_rows),
         }
-    if obs.enabled():
-        reg = obs.registry()
-        if schedule_len is not None:
-            reg.gauge(
-                "fit.solver_schedule_len", backend="aggregate"
-            ).set(float(schedule_len))
-        if escalations:
-            reg.counter(
-                "fit.solver_escalations", backend="aggregate"
-            ).inc(escalations)
-        if dispatched_rows:
-            reg.gauge("fit.class_batch_padding_waste").set(
-                padded_rows / float(dispatched_rows)
-            )
+    if obs.enabled() and dispatched_rows:
+        obs.registry().gauge("fit.class_batch_padding_waste").set(
+            padded_rows / float(dispatched_rows)
+        )
     return out
 
 

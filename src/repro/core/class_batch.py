@@ -46,17 +46,11 @@ of this module) and structure-exact vs the unpadded sequential fit, with
 coefficients differing only by the fp summation-order drift of the longer
 (zero-extended) Gram reduction.
 
-Oracle / WIHB configs additionally swap the data-dependent ``while_loop``
-solvers for their masked fixed-schedule twins
-(:mod:`repro.core.oracles`, ``solve_*_scheduled``): all classes share one
-static iteration budget, converged lanes carry state as bitwise no-ops, and
-whenever any valid lane reports an unconverged solve the driver doubles the
-budget (pow2 buckets, mirroring capacity regrowth) and re-dispatches the
-same degree — safe because the batched step donates nothing.  Escalated to
-convergence, the fixed-schedule iterates compose exactly like the
-``while_loop`` refs, so the bit-exactness contract above carries over to
-oracle engines unchanged; the escalation trajectory is a deterministic
-function of the data, so warm refits replay it with zero recompiles.
+Oracle / WIHB configs run the convex oracles' ``while_loop`` solvers
+(:mod:`repro.core.oracles`) under the same ``vmap``: a batched solve runs
+until its slowest lane stops, and stopped lanes carry state as bitwise
+no-ops, so the bit-exactness contract above carries over to oracle engines
+unchanged.
 
 Distribution composes: with a mesh, the class axis (vmap) nests inside the
 data-sharded ``shard_map`` psum path — see
@@ -74,8 +68,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ihb as ihb_mod
-from . import oracles as oracles_mod
 from .. import obs
+from ..kernels import ops as kernel_ops
 from . import terms as terms_mod
 from .oavi import (
     FitScope,
@@ -122,36 +116,25 @@ def _init_batch_arrays(mask, Lcap: int, factors):
     return A, state
 
 
-def _batched_entry(config: OAVIConfig, mesh, data_axes, schedule=None):
+def _batched_entry(config: OAVIConfig, mesh, data_axes):
     """Cached jitted batched step: plain ``jit(vmap(step))`` locally, the
-    vmap-inside-shard_map composition when a mesh is given.  ``schedule``
-    (oracle/WIHB configs) selects the fixed-schedule solver budget and is
-    part of the cache key — each escalation level is its own jitted step, so
-    a warm refit replaying the same escalations compiles nothing."""
+    vmap-inside-shard_map composition when a mesh is given."""
     if mesh is None:
         return degree_step_entry(
             config,
-            backend_key=("class_batch", schedule),
-            jitted_builder=lambda: jax.jit(
-                jax.vmap(_make_degree_step(config, schedule=schedule))
-            ),
+            backend_key="class_batch",
+            jitted_builder=lambda: jax.jit(jax.vmap(_make_degree_step(config))),
         )
     from . import distributed as distributed_mod
 
     axes = tuple(data_axes)
     return degree_step_entry(
         config,
-        backend_key=("class_batch", mesh, axes, schedule),
+        backend_key=("class_batch", mesh, axes),
         jitted_builder=lambda: distributed_mod.make_class_batched_sharded_degree_step(
-            config, mesh, axes, schedule=schedule
+            config, mesh, axes
         ),
     )
-
-
-def needs_solver_schedule(config: OAVIConfig) -> bool:
-    """Whether batched fits of this config must run the fixed-schedule
-    solvers (any path that invokes a convex oracle under ``vmap``)."""
-    return config.engine == "oracle" or config.wihb
 
 
 def fit_classes(
@@ -204,6 +187,7 @@ def fit_classes(
         "recompiles": 0,
         "regrowths": 0,
         "degree_times": [],
+        "gram_tiles": 0,
         "m": int(sum(ms)),
         "n": n,
     }
@@ -263,17 +247,6 @@ def fit_classes(
             ells = [1] * k
             active = [True] * k
 
-            # Fixed-schedule solver budget (oracle/WIHB configs): starts at the
-            # config's pow2 bucket, doubles whenever any lane's solve was cut
-            # short, persists across degrees (like capacity, it only grows).
-            schedule = (
-                oracles_mod.schedule_budget(config.solver)
-                if needs_solver_schedule(config)
-                else None
-            )
-            batch["solver_schedule_len"] = schedule
-            batch["solver_escalations"] = 0
-
             m_total = jnp.asarray([float(m) for m in ms], dtype)
 
             per_class = [init_fit_stats(ms[c], n) for c in range(k)]
@@ -321,37 +294,26 @@ def fit_classes(
                             books[c], borders[c], Kcap
                         )
 
+                # the Gram kernel's output tiles at this degree's capacities
+                tiles = kernel_ops.gram_tiles(Lcap, Kcap, n)
+                batch["gram_tiles"] += tiles
+                obs.registry().counter("gram/tiles").inc(tiles)
+
                 ells_d = jnp.asarray(ells, jnp.int32)
                 parents_d = jnp.asarray(parents)
                 vars_d = jnp.asarray(vars_)
                 valid_d = jnp.asarray(valid)
 
             with scope.degree(d, K=int(max(Ks)), k=k):
-                # Escalation loop: the batched step donates nothing, so on an
-                # unconverged budget we simply double the schedule and re-run
-                # the same degree from the same inputs (iteration chunks
-                # compose exactly — the longer run replays the shorter one's
-                # iterations bit-for-bit, then continues).
-                while True:
-                    entry = _batched_entry(config, mesh, data_axes, schedule)
-                    sig = (k, mc, n, Lcap, Kcap, str(dtype), schedule)
-                    step_args = (
-                        A, Xd, state, ells_d, parents_d, vars_d, valid_d, m_total
-                    )
-                    scope.note_signature(entry.seen, sig)
-                    A_next, st = entry.fn(*step_args)
-                    # one host sync per degree: the escalation verdict rides
-                    # the same transfer as the accept/reject results
-                    accepted, mses, coeffs, iters, unconverged = jax.device_get(
-                        (st.accepted, st.mses, st.coeffs, st.iters, st.unconverged)
-                    )
-                    if schedule is None or not bool(np.any(unconverged)):
-                        break
-                    if schedule >= oracles_mod.max_schedule(config.solver):
-                        break
-                    schedule = oracles_mod.escalate_schedule(config.solver, schedule)
-                    batch["solver_escalations"] += 1
-                A = A_next
+                entry = _batched_entry(config, mesh, data_axes)
+                scope.note_signature(entry.seen, (k, mc, n, Lcap, Kcap, str(dtype)))
+                A, st = entry.fn(
+                    A, Xd, state, ells_d, parents_d, vars_d, valid_d, m_total
+                )
+                # one host sync per degree
+                accepted, mses, coeffs, iters = jax.device_get(
+                    (st.accepted, st.mses, st.coeffs, st.iters)
+                )
                 state = st.ihb
 
             with obs.span("fit/collect"):
@@ -364,17 +326,6 @@ def fit_classes(
                         generators[c],
                     )
 
-        batch["solver_schedule_len"] = schedule
-        # publish the solver-discipline outcome so obs_report can diagnose
-        # the escalation-bound regime (one hard lane taxing a whole batch)
-        if schedule is not None:
-            obs.registry().gauge(
-                "fit.solver_schedule_len", backend="class_batch"
-            ).set(float(schedule))
-        if batch["solver_escalations"]:
-            obs.registry().counter(
-                "fit.solver_escalations", backend="class_batch"
-            ).inc(batch["solver_escalations"])
         models: List[OAVIModel] = []
         for c in range(k):
             stats = per_class[c]
@@ -383,8 +334,7 @@ def fit_classes(
             stats["recompiles"] = batch["recompiles"]
             stats["regrowths"] = batch["regrowths"]
             stats["degree_times"] = list(batch["degree_times"])
-            stats["solver_schedule_len"] = schedule
-            stats["solver_escalations"] = batch["solver_escalations"]
+            stats["gram_tiles"] = batch["gram_tiles"]
             stats["class_batch"] = {
                 "group": batch["group"],
                 "size": k,

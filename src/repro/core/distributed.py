@@ -126,7 +126,6 @@ def make_sharded_degree_step(
 
 def make_class_batched_sharded_degree_step(
     cfg: OAVIConfig, mesh: Mesh, data_axes: Sequence[str] = ("data",),
-    schedule=None,
 ):
     """Class-batched AND data-sharded degree step: the class axis (``vmap``)
     composed with the sample-sharded psum path.
@@ -137,15 +136,10 @@ def make_class_batched_sharded_degree_step(
     one psum per degree (now carrying ``(k, L, K) + (k, K, K)`` floats, still
     m-independent) replicates the blocks.  The candidate loop then replays
     bit-identically on every device for all classes at once.
-
-    ``schedule`` (oracle/WIHB configs) selects the fixed-schedule solver
-    budget the vmapped candidate loop runs at — see
-    :func:`repro.core.class_batch._batched_entry`, which owns the escalation
-    protocol and cache keying.
     """
     axes = tuple(data_axes)
     reduce_fn = lambda x: jax.lax.psum(x, axes)  # noqa: E731
-    step = jax.vmap(_make_degree_step(cfg, reduce_fn=reduce_fn, schedule=schedule))
+    step = jax.vmap(_make_degree_step(cfg, reduce_fn=reduce_fn))
     # probe outside the vmap, inside the shard_map: one instant per device
     # per dispatch (not per class)
     step = shard_probe(step, mesh, axes, "fit/shard_step")

@@ -69,12 +69,10 @@ from ..kernels import ops as kernel_ops
 from . import ihb as ihb_mod
 from . import terms as terms_mod
 from .oracles import (
-    SCHEDULED_SOLVERS,
     SOLVERS,
     OracleConfig,
     solve_agd,
     solve_bpcg,
-    solve_bpcg_scheduled,
     solve_cg,
     solve_pcg,
 )
@@ -442,10 +440,6 @@ class _LoopState(NamedTuple):
     coeffs: jax.Array  # (K, L)
     mses: jax.Array  # (K,)
     iters: jax.Array  # (K,) solver iterations (0 for pure closed-form)
-    # bool: some valid candidate's fixed-schedule solve was cut short by the
-    # iteration budget — the driver must escalate the schedule and re-dispatch
-    # (always False for the while_loop refs and the 'fast' engine).
-    unconverged: jax.Array
 
 
 def _kernel_kwargs(cfg: OAVIConfig) -> Dict:
@@ -457,7 +451,7 @@ def _kernel_kwargs(cfg: OAVIConfig) -> Dict:
     }[cfg.kernel]
 
 
-def _make_stats_degree_step(cfg: OAVIConfig, reduce_fn=None, schedule=None):
+def _make_stats_degree_step(cfg: OAVIConfig, reduce_fn=None):
     """Build the *statistics-only* degree step: every accept/reject decision
     of one degree from the raw Gram sufficient statistics alone — the
     evaluation matrix A never enters.  This is the piece the out-of-core fit
@@ -466,24 +460,14 @@ def _make_stats_degree_step(cfg: OAVIConfig, reduce_fn=None, schedule=None):
     computation and the A column scatter.  ``reduce_fn`` (e.g. a psum) is
     applied to the raw Gram quantities; None means single-device.
 
-    ``schedule`` selects the solver discipline for oracle/WIHB configs:
-    ``None`` uses the data-dependent ``while_loop`` solvers (cheapest for a
-    single sequential fit — they stop the moment a certificate fires), a
-    static int uses the masked fixed-schedule solvers (vmap-bit-stable, so
-    the step can ride the class-batched / streaming-batched paths).  When a
-    valid lane's scheduled solve is cut short, the returned
-    ``_LoopState.unconverged`` is True and the driver escalates (x2) and
-    re-dispatches — iteration chunks compose exactly, so escalating to
-    convergence reproduces the while_loop results bit-for-bit."""
+    Oracle/WIHB configs run the ``while_loop`` solvers of
+    :mod:`repro.core.oracles`, which stop the moment a certificate fires;
+    under ``vmap`` (the class-batched / streaming-batched paths) a solve
+    runs until its slowest lane stops, and every lane keeps the bits of its
+    own single solve."""
 
-    scheduled = schedule is not None
-    if scheduled:
-        schedule = int(schedule)
-        solver = partial(SCHEDULED_SOLVERS[cfg.solver.name], schedule=schedule)
-        wihb_solver = partial(solve_bpcg_scheduled, schedule=schedule)
-    else:
-        solver = SOLVERS[cfg.solver.name]
-        wihb_solver = solve_bpcg
+    solver = SOLVERS[cfg.solver.name]
+    wihb_solver = solve_bpcg
     use_chol = cfg.inverse_engine == "chol"
     engine_oracle = cfg.engine == "oracle"
     # closed-form optimum needed: always for 'fast', as a warm start otherwise
@@ -524,7 +508,6 @@ def _make_stats_degree_step(cfg: OAVIConfig, reduce_fn=None, schedule=None):
                     y0 = -u
                 y0 = jnp.where(mask, y0, 0.0)
 
-            unconverged = st.unconverged
             if not engine_oracle:
                 # sum(q * y0), not q @ y0: the elementwise+reduce lowering is
                 # bit-stable under vmap (class-batched fit); a fused dot is not
@@ -548,34 +531,21 @@ def _make_stats_degree_step(cfg: OAVIConfig, reduce_fn=None, schedule=None):
                     warm = jnp.zeros((Lcap,), dtype)
                 res = solver(st.ihb.AtA, q, btb, one, mask, psi, cfg.solver, warm)
                 y, mse_final, it = res.y, res.f, res.iters
-                if scheduled:
-                    unconverged = unconverged | (valid[a] & ~res.converged)
 
             accept = (mse_final <= psi) & valid[a]
 
             if cfg.wihb:
                 # re-solve accepted generators sparsely from a cold start
-                if scheduled:
-                    # select-based (both branches computed) so the step stays
-                    # bit-stable under vmap; the kept values are identical to
-                    # the lax.cond form either way.
-                    res2 = wihb_solver(st.ihb.AtA, q, btb, one, mask, psi, cfg.solver, None)
-                    ok = res2.f <= psi
-                    take = accept & ok
-                    y = jnp.where(take, res2.y, y)
-                    mse_final = jnp.where(take, res2.f, mse_final)
-                    it = it + jnp.where(accept, res2.iters, 0)
-                    unconverged = unconverged | (accept & ~res2.converged)
-                else:
-                    def resolve():
-                        res = wihb_solver(st.ihb.AtA, q, btb, one, mask, psi, cfg.solver, None)
-                        ok = res.f <= psi
-                        return jnp.where(ok, res.y, y), jnp.where(ok, res.f, mse_final), res.iters
+                # (under vmap the cond computes both branches and selects)
+                def resolve():
+                    res = wihb_solver(st.ihb.AtA, q, btb, one, mask, psi, cfg.solver, None)
+                    ok = res.f <= psi
+                    return jnp.where(ok, res.y, y), jnp.where(ok, res.f, mse_final), res.iters
 
-                    y, mse_final, extra = jax.lax.cond(
-                        accept, resolve, lambda: (y, mse_final, jnp.asarray(0, jnp.int32))
-                    )
-                    it = it + extra
+                y, mse_final, extra = jax.lax.cond(
+                    accept, resolve, lambda: (y, mse_final, jnp.asarray(0, jnp.int32))
+                )
+                it = it + extra
 
             # On reject: append column to O (slot = ell), update Gram/inverse.
             do_append = (~accept) & valid[a]
@@ -597,7 +567,6 @@ def _make_stats_degree_step(cfg: OAVIConfig, reduce_fn=None, schedule=None):
                 coeffs=st.coeffs.at[a].set(jnp.where(accept, y, 0.0)),
                 mses=st.mses.at[a].set(mse_final),
                 iters=st.iters.at[a].set(it),
-                unconverged=unconverged,
             )
             return st
 
@@ -610,19 +579,18 @@ def _make_stats_degree_step(cfg: OAVIConfig, reduce_fn=None, schedule=None):
             coeffs=jnp.zeros((K, Lcap), dtype),
             mses=jnp.zeros((K,), dtype),
             iters=jnp.zeros((K,), jnp.int32),
-            unconverged=jnp.asarray(False),
         )
         return jax.lax.fori_loop(0, K, body, st0)
 
     return stats_step
 
 
-def _make_degree_step(cfg: OAVIConfig, reduce_fn=None, schedule=None):
+def _make_degree_step(cfg: OAVIConfig, reduce_fn=None):
     """Build the jitted in-memory degree step: the fused Gram computation,
     the statistics-only acceptance loop (:func:`_make_stats_degree_step`),
     and the scatter of appended candidate columns into A."""
 
-    stats_step = _make_stats_degree_step(cfg, reduce_fn, schedule=schedule)
+    stats_step = _make_stats_degree_step(cfg, reduce_fn)
     gram_kw = _kernel_kwargs(cfg)
 
     def degree_step(A, X, state: ihb_mod.IHBState, ell0, parents, vars_, valid, m_total):
@@ -696,15 +664,13 @@ def class_batchable(config: OAVIConfig) -> bool:
     (:mod:`repro.core.class_batch`).
 
     The batched path guarantees bit-exactness against the sequential fit at
-    matched capacity and solver schedule, which restricts it to
-    configurations whose degree step is built from vmap-bit-stable
-    primitives (batched matmuls/matvecs match their per-slice counterparts
-    on every backend we test).  Every engine qualifies now that the convex
-    oracles have masked fixed-schedule twins (:mod:`repro.core.oracles`):
-    oracle and WIHB configs run the ``solve_*_scheduled`` solvers under
-    ``vmap`` — converged lanes ride as bitwise no-ops, and the driver
-    escalates the shared schedule until every lane converges, which
-    reproduces the per-class ``while_loop`` results bit-for-bit.
+    matched capacity, which restricts it to configurations whose degree step
+    is built from vmap-bit-stable primitives (batched matmuls/matvecs match
+    their per-slice counterparts on every backend we test).  Every engine
+    qualifies, the convex oracles included (:mod:`repro.core.oracles`):
+    under ``vmap`` their ``while_loop`` runs until the slowest lane stops,
+    and stopped lanes ride as bitwise no-ops, which reproduces the per-class
+    results bit-for-bit.
 
     The one remaining exclusion is ``inverse_engine='chol'``: batched
     triangular solves do not reduce in the same order as their
@@ -748,11 +714,6 @@ def init_fit_stats(m: int, n: int, **extra) -> Dict:
         "degree_times": [],
         "recompiles": 0,
         "regrowths": 0,
-        # fixed-schedule solver discipline (batched oracle/WIHB fits only):
-        # final per-solve iteration budget and how many times the loop had to
-        # escalate it; None/0 on paths using the while_loop refs.
-        "solver_schedule_len": None,
-        "solver_escalations": 0,
         # XLA backend-compile seconds attributed to this fit (repro.obs.device)
         "compile_seconds": 0.0,
         "time_total": 0.0,

@@ -22,23 +22,15 @@ Early-termination rules follow Section 6.1 of the paper:
     ``f* > psi`` for CG variants),
   * hard iteration cap.
 
-Each solver comes in two executions of the *same* per-iteration body:
+Each solver is one ``while_loop`` over its early-termination predicate
+(``_run_while``): it stops the moment a certificate fires.  The same loop
+rides the class-batched / streaming paths under ``vmap``: the batched loop
+runs until its last lane stops, and a stopped lane carries its state as a
+bitwise no-op (the same trick ``class_batch`` uses for finished classes),
+so a lane's result does not depend on how long the others run, and a batch
+pays for its slowest lane.  Each solver's ``cond``/``body``/``finish`` closures come
+from its ``_*_parts`` helper.
 
-  * ``solve_*`` — a data-dependent ``while_loop`` over the early-termination
-    predicate.  Cheapest for a single cold solve (stops the moment a
-    certificate fires) but the trip count is data-dependent, so it is not
-    vmap-bit-stable and cannot ride the class-batched / streaming paths.
-  * ``solve_*_scheduled`` — a fixed-schedule ``fori_loop`` over a static
-    iteration budget where the early-termination predicate becomes a per-lane
-    active mask: converged lanes carry their state as bitwise no-ops (the
-    same trick ``class_batch`` uses for finished classes).  Batched fit loops
-    escalate the budget (x2, pow2 buckets — mirroring capacity regrowth)
-    while any lane reports ``converged == False``; because iteration chunks
-    compose exactly, a scheduled solve escalated to convergence is
-    bit-identical to the while_loop ref.
-
-Both paths share ``cond``/``body``/``finish`` closures built by the per-solver
-``_*_parts`` helpers, so parity is structural rather than numerical luck.
 All vector reductions use :func:`vdot` (elementwise multiply + sum) instead of
 fused ``a @ b`` dots: the fused form lowers to a different reduction order
 under ``vmap``, which would break the bit-identity contract between a batched
@@ -57,11 +49,6 @@ import jax.numpy as jnp
 NEG_INF = -jnp.inf
 
 
-def next_pow2(x: int) -> int:
-    """Smallest power of two >= max(x, 1) (schedule buckets)."""
-    return 1 << max(int(x) - 1, 0).bit_length()
-
-
 @dataclasses.dataclass(frozen=True)
 class OracleConfig:
     name: str = "bpcg"  # 'agd' | 'cg' | 'pcg' | 'bpcg'
@@ -70,44 +57,6 @@ class OracleConfig:
     eps_frac: float = 0.01  # solver accuracy = eps_frac * psi
     # AGD: number of power iterations used to estimate the smoothness constant
     power_iters: int = 30
-    # Fixed-schedule path: initial per-solve iteration budget (pow2-bucketed
-    # by schedule_budget).  This only sets where device-side escalation
-    # starts, never the reachable accuracy — batched fit loops double it
-    # until every lane converges or max_iter is reached.  The default, 0, is
-    # a certificate-check-only start: the solver state is initialized from
-    # the warm start and the early-termination predicate is evaluated at
-    # entry without running a single iteration — for IHB-warm solves (the
-    # paper's flagship configs) the closed-form warm start already fires a
-    # certificate, so budget 0 costs one gradient/gap evaluation per lane,
-    # within epsilon of the early-exit while_loop ref.  Cold configs
-    # escalate geometrically (0 -> 1 -> 2 -> ...) to whatever they need, and
-    # the budget persists across degrees, so the escalation bill is paid
-    # once per fit, not once per degree.
-    schedule: int = 0
-
-
-def schedule_budget(cfg: OracleConfig) -> int:
-    """Initial fixed-schedule iteration budget: purely config-driven.
-
-    pow2-bucketed (0 allowed: certificate-check only) so refits under the
-    same config reuse the same compiled step.  Deliberately NOT
-    capacity-coupled: a masked fixed-schedule lane pays its full budget in
-    FLOPs whether or not it converged earlier, so over-provisioning the
-    start burns more than the escalation re-dispatch it would save —
-    warm-started solves finish in O(1) iterations at any base size, and
-    cold solves find their level in log2(need) doublings."""
-    s = max(int(cfg.schedule), 0)
-    return min(next_pow2(int(cfg.max_iter)), next_pow2(s) if s else 0)
-
-
-def max_schedule(cfg: OracleConfig) -> int:
-    """Budget at which every lane is guaranteed ``converged`` (the
-    ``k < max_iter`` clause falsifies the active mask)."""
-    return next_pow2(int(cfg.max_iter))
-
-
-def escalate_schedule(cfg: OracleConfig, schedule: int) -> int:
-    return min(max_schedule(cfg), max(int(schedule) * 2, 1))
 
 
 class SolveResult(NamedTuple):
@@ -115,11 +64,6 @@ class SolveResult(NamedTuple):
     f: jax.Array  # objective value (MSE of the candidate polynomial)
     gap: jax.Array  # FW gap (CG variants) or squared grad norm (AGD)
     iters: jax.Array  # iterations used
-    # True when the early-termination predicate held at exit: a certificate
-    # fired, the accuracy target was met, or max_iter was reached.  Always
-    # True for the while_loop refs; False from a fixed-schedule solver means
-    # the budget cut the iteration short and the caller should escalate.
-    converged: jax.Array = True
 
 
 def _mv(Q, y):
@@ -153,26 +97,13 @@ def _line_search_quad(Q, inv_m, grad, d, gamma_max):
 
 
 # --------------------------------------------------------------------------
-# Shared runners: one body, two trip-count disciplines
+# Shared runner
 # --------------------------------------------------------------------------
 
 
 def _run_while(state0, cond, body, finish) -> "SolveResult":
     final = jax.lax.while_loop(cond, body, state0)
     return finish(final)
-
-
-def _run_scheduled(state0, cond, body, finish, schedule: int) -> "SolveResult":
-    def step(_, st):
-        active = cond(st)
-        nxt = body(st)
-        return jax.tree_util.tree_map(
-            lambda new, old: jnp.where(active, new, old), nxt, st
-        )
-
-    final = jax.lax.fori_loop(0, int(schedule), step, state0)
-    res = finish(final)
-    return res._replace(converged=jnp.logical_not(cond(final)))
 
 
 # --------------------------------------------------------------------------
@@ -221,7 +152,7 @@ def _agd_parts(Q, q, btb, m, mask, psi, cfg: OracleConfig, y0):
     def finish(state):
         y, _, _, k, gnorm2 = state
         f = quad_f(Q, q, btb, inv_m, y)
-        return SolveResult(y=y, f=f, gap=gnorm2, iters=k, converged=jnp.asarray(True))
+        return SolveResult(y=y, f=f, gap=gnorm2, iters=k)
 
     g0 = quad_grad(Q, q, inv_m, y0) * maskf
     state0 = (y0, y0, jnp.asarray(1.0, dtype), jnp.asarray(0, jnp.int32), vdot(g0, g0))
@@ -240,14 +171,6 @@ def solve_agd(
     y0: Optional[jax.Array] = None,
 ) -> SolveResult:
     return _run_while(*_agd_parts(Q, q, btb, m, mask, psi, cfg, y0))
-
-
-@partial(jax.jit, static_argnames=("cfg", "schedule"))
-def solve_agd_scheduled(Q, q, btb, m, mask, psi, cfg: OracleConfig, y0=None,
-                        schedule: Optional[int] = None) -> SolveResult:
-    if schedule is None:
-        schedule = schedule_budget(cfg)
-    return _run_scheduled(*_agd_parts(Q, q, btb, m, mask, psi, cfg, y0), schedule)
 
 
 # --------------------------------------------------------------------------
@@ -309,9 +232,8 @@ def _fw_state0(Q, q, btb, inv_m, y0, wp0, wm0, mask, r):
     With the real gap known at iteration 0, the Section 6.1 certificates can
     fire before any step is taken: a warm start that already vanishes
     (``f <= psi``) or is certifiably infeasible (``f - gap > psi``) makes the
-    whole solve a no-op — which is what lets the fixed-schedule path run
-    IHB-warm fits at budget 0 (certificate check only) instead of paying a
-    full masked iteration per lane just to learn the gap."""
+    whole solve a no-op, so an IHB-warm solve whose closed form already
+    settles the candidate runs no iteration at all."""
     maskf = mask.astype(Q.dtype)
     Qy = _mv(Q, y0)  # shared between f0 and grad: one matvec, not two
     f0 = (vdot(y0, Qy) + 2.0 * vdot(q, y0) + btb) * inv_m
@@ -323,8 +245,7 @@ def _fw_state0(Q, q, btb, inv_m, y0, wp0, wm0, mask, r):
 
 
 def _fw_finish(state: _FWState) -> SolveResult:
-    return SolveResult(y=state.y, f=state.f, gap=state.gap, iters=state.k,
-                       converged=jnp.asarray(True))
+    return SolveResult(y=state.y, f=state.f, gap=state.gap, iters=state.k)
 
 
 def _cg_parts(Q, q, btb, m, mask, psi, cfg: OracleConfig, y0):
@@ -479,14 +400,6 @@ def _bpcg_parts(Q, q, btb, m, mask, psi, cfg: OracleConfig, y0):
     return state0, partial(_fw_cond, cfg, psi), body, _fw_finish
 
 
-_PARTS = {
-    "agd": _agd_parts,
-    "cg": _cg_parts,
-    "pcg": _pcg_parts,
-    "bpcg": _bpcg_parts,
-}
-
-
 @partial(jax.jit, static_argnames=("cfg",))
 def solve_cg(Q, q, btb, m, mask, psi, cfg: OracleConfig, y0=None) -> SolveResult:
     return _run_while(*_cg_parts(Q, q, btb, m, mask, psi, cfg, y0))
@@ -502,24 +415,6 @@ def solve_bpcg(Q, q, btb, m, mask, psi, cfg: OracleConfig, y0=None) -> SolveResu
     return _run_while(*_bpcg_parts(Q, q, btb, m, mask, psi, cfg, y0))
 
 
-def _make_scheduled(name: str):
-    @partial(jax.jit, static_argnames=("cfg", "schedule"))
-    def solve_scheduled_one(Q, q, btb, m, mask, psi, cfg: OracleConfig, y0=None,
-                            schedule: Optional[int] = None) -> SolveResult:
-        if schedule is None:
-            schedule = schedule_budget(cfg)
-        parts = _PARTS[name](Q, q, btb, m, mask, psi, cfg, y0)
-        return _run_scheduled(*parts, schedule)
-
-    solve_scheduled_one.__name__ = f"solve_{name}_scheduled"
-    return solve_scheduled_one
-
-
-solve_cg_scheduled = _make_scheduled("cg")
-solve_pcg_scheduled = _make_scheduled("pcg")
-solve_bpcg_scheduled = _make_scheduled("bpcg")
-
-
 SOLVERS = {
     "agd": solve_agd,
     "cg": solve_cg,
@@ -527,19 +422,7 @@ SOLVERS = {
     "bpcg": solve_bpcg,
 }
 
-SCHEDULED_SOLVERS = {
-    "agd": solve_agd_scheduled,
-    "cg": solve_cg_scheduled,
-    "pcg": solve_pcg_scheduled,
-    "bpcg": solve_bpcg_scheduled,
-}
-
 
 def solve(Q, q, btb, m, mask, psi, cfg: OracleConfig, y0=None) -> SolveResult:
     return SOLVERS[cfg.name](Q, q, btb, m, mask, psi, cfg, y0)
 
-
-def solve_scheduled(Q, q, btb, m, mask, psi, cfg: OracleConfig, y0=None,
-                    schedule: Optional[int] = None) -> SolveResult:
-    return SCHEDULED_SOLVERS[cfg.name](Q, q, btb, m, mask, psi, cfg, y0,
-                                       schedule=schedule)

@@ -7,8 +7,7 @@ construction is dispatched through :func:`repro.api.fit_classes` — with
 ``class_batch="auto"`` (default) eligible per-class OAVI fits are grouped
 into shared pow2 row buckets and driven through ONE vmapped jitted degree
 step (:mod:`repro.core.class_batch`; bit-exact vs sequential at matched
-capacity) — oracle/WIHB configs run their masked fixed-schedule solvers
-under the vmap, stragglers fold into their cheapest warm bucket, and only
+capacity) — oracle/WIHB configs run their solvers under the vmap, stragglers fold into their cheapest warm bucket, and only
 the Cholesky engine falls back to sequential fits — the feature transform
 runs through the fused
 :func:`repro.api.feature_transform`, and the features are classified by the
@@ -64,8 +63,8 @@ class PipelineConfig:
     # 'auto': batch eligible per-class OAVI fits through one vmapped degree
     # step, grouped into shared pow2 row buckets with stragglers folded into
     # their cheapest warm bucket (repro.core.class_batch.plan_class_groups);
-    # oracle/WIHB configs use the masked fixed-schedule solvers, only the
-    # chol engine falls back to sequential.  'off': always sequential.
+    # oracle/WIHB configs included, only the chol engine falls back to
+    # sequential.  'off': always sequential.
     class_batch: str = "auto"
     # out-of-core generator construction: when set, each per-class OAVI fit
     # streams through repro.streaming.fit in chunk_rows-row chunks instead of
@@ -237,8 +236,9 @@ class VanishingIdealClassifier:
                 "recompiles": agg["recompiles"],
                 "regrowths": agg["regrowths"],
                 "class_batched": agg["class_batched"],
-                "solver_schedule_len": agg["solver_schedule_len"],
-                "solver_escalations": agg["solver_escalations"],
+                # the solvers never re-dispatch a degree; the key stays for
+                # readers of fit stats (bench/drivers/fit_repeat.py)
+                "solver_escalations": 0,
                 "per_class": gen_stats,
                 "svm": self.svm.stats,
             }

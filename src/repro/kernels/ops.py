@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from . import ref
 from . import svm_grad as _svm_grad
 from .flash_attention import flash_attention as _flash_kernel
+from . import gram_update as _gram
 from .gram_update import gram_update as _gram_kernel
 from .gram_update import gram_update_acc as _gram_acc_kernel
 from .ihb_update import ihb_update as _ihb_kernel
@@ -101,6 +102,14 @@ def gram_accumulate(
         return ref.gram_accumulate_ref(A, X, parents, vars_, acc[0], acc[1], bm=bm)
     Psel, Vsel = selection_matrices(parents, vars_, L, n, A.dtype)
     return _gram_acc_kernel(A, X, Psel, Vsel, acc[0], acc[1], bm=bm, interpret=interpret)
+
+
+def gram_tiles(L: int, K: int, n: int, bm: int = GRAM_BLOCK) -> int:
+    """Output tiles of the Gram kernel's grid for ``(L, K)`` at ``n``
+    variables: its ``(j, i)`` positions, each a tile of ``QL``, of ``C`` or
+    of both; 1 where the kernel holds both whole."""
+    tl, tk = _gram.tiles(L, K, n, bm)
+    return (K // tk) * max(L // tl, K // tk)
 
 
 def ihb_update(N, q, btb, ell, *, u=None, use_pallas=None, interpret=False):

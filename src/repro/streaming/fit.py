@@ -541,16 +541,13 @@ def fit(
 # ---------------------------------------------------------------------------
 
 
-def _streaming_class_entry(config: OAVIConfig, schedule):
+def _streaming_class_entry(config: OAVIConfig):
     """Cached jitted ``vmap`` of the statistics-only degree step over a class
-    axis; ``schedule`` (oracle/WIHB configs) is part of the cache key so each
-    escalation level is its own compiled step."""
+    axis."""
     return degree_step_entry(
         config,
-        backend_key=("streaming_class_batch", schedule),
-        jitted_builder=lambda: jax.jit(
-            jax.vmap(_make_stats_degree_step(config, schedule=schedule))
-        ),
+        backend_key="streaming_class_batch",
+        jitted_builder=lambda: jax.jit(jax.vmap(_make_stats_degree_step(config))),
     )
 
 
@@ -572,9 +569,8 @@ def fit_classes(
     dispatch-bound part of a streaming fit — are stacked into ``(k, Lcap,
     Kcap)`` statistics and decided in one dispatch.  Finished classes ride
     along with all-``False`` validity masks (their zeroed accumulators make
-    the slice a bitwise no-op); oracle/WIHB configs run the fixed-schedule
-    solvers with the same budget-escalation protocol as the in-memory batch
-    (the stats step donates nothing, so re-dispatch is safe).
+    the slice a bitwise no-op); oracle/WIHB configs run their solvers under
+    the same ``vmap`` as the in-memory batch.
 
     Bit-exact against per-class :func:`fit` calls at matched capacity (the
     shared ``Lcap`` growth schedule — the accumulated statistics themselves
@@ -582,7 +578,6 @@ def fit_classes(
     sharded streaming path stays per-class.
     """
     from ..core import class_batch as class_batch_mod
-    from ..core import oracles as oracles_mod
 
     sources = [as_source(s) for s in sources]
     chunk_rows = _check_chunk_rows(chunk_rows)
@@ -641,12 +636,6 @@ def fit_classes(
             ),
             k,
         )
-        schedule = (
-            oracles_mod.schedule_budget(config.solver)
-            if class_batch_mod.needs_solver_schedule(config)
-            else None
-        )
-        batch["solver_escalations"] = 0
 
         m_total = jnp.asarray([float(m) for m in ms], dtype)
         per_class = [
@@ -734,22 +723,10 @@ def fit_classes(
                 ells_d = jnp.asarray(ells, jnp.int32)
                 valid_d = jnp.asarray(valid)
 
-                # ONE vmapped stats step for all classes; escalate the solver
-                # schedule while any valid lane's budget was cut short
-                while True:
-                    entry = _streaming_class_entry(config, schedule)
-                    csig = (k, Lcap, Kcap, str(dtype), schedule)
-                    cargs = (accQL_b, accC_b, state, ells_d, valid_d, m_total)
-                    scope.note_signature(entry.seen, csig)
-                    st = entry.fn(*cargs)
-                    if schedule is None or not bool(
-                        np.any(jax.device_get(st.unconverged))
-                    ):
-                        break
-                    if schedule >= oracles_mod.max_schedule(config.solver):
-                        break
-                    schedule = oracles_mod.escalate_schedule(config.solver, schedule)
-                    batch["solver_escalations"] += 1
+                # ONE vmapped stats step for all classes
+                entry = _streaming_class_entry(config)
+                scope.note_signature(entry.seen, (k, Lcap, Kcap, str(dtype)))
+                st = entry.fn(accQL_b, accC_b, state, ells_d, valid_d, m_total)
                 state = st.ihb
                 accepted, mses, coeffs, iters = jax.device_get(
                     (st.accepted, st.mses, st.coeffs, st.iters)
@@ -764,23 +741,12 @@ def fit_classes(
                     generators[c],
                 )
 
-        batch["solver_schedule_len"] = schedule
-        if schedule is not None:
-            obs.registry().gauge(
-                "fit.solver_schedule_len", backend="streaming_class_batch"
-            ).set(float(schedule))
-        if batch["solver_escalations"]:
-            obs.registry().counter(
-                "fit.solver_escalations", backend="streaming_class_batch"
-            ).inc(batch["solver_escalations"])
         models: List[OAVIModel] = []
         for c in range(k):
             stats = per_class[c]
             stats["recompiles"] = batch["recompiles"]
             stats["regrowths"] = batch["regrowths"]
             stats["degree_times"] = list(batch["degree_times"])
-            stats["solver_schedule_len"] = schedule
-            stats["solver_escalations"] = batch["solver_escalations"]
             stats["class_batch"] = {
                 "group": batch["group"],
                 "size": k,

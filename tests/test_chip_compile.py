@@ -70,7 +70,7 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("n,L,K", [(3, 64, 64), (57, 256, 512)])
+@pytest.mark.parametrize("n,L,K", [(3, 64, 64), (57, 256, 512), (57, 2048, 2048)])
 def test_gram_update_acc_compiles(sds, n, L, K):
     bm, blocks = 256, 4
     m = bm * blocks
@@ -89,8 +89,8 @@ def test_ihb_update_compiles(sds, L):
     assert _has_kernel(compiled)
 
 
-def test_ihb_update_vmapped_compiles(sds):
-    k, L = 8, 1024
+@pytest.mark.parametrize("k,L", [(8, 1024), (2, 2048)])
+def test_ihb_update_vmapped_compiles(sds, k, L):
     compiled = jax.jit(jax.vmap(ihb_update)).lower(
         sds((k, L, L)), sds((k, L)), sds((k,)), sds((k,), I32)
     ).compile()
@@ -113,6 +113,29 @@ def test_degree_step_with_pallas_kernels_compiles(sds):
     ).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 2, "expected the Gram and IHB kernels"
+
+
+def test_class_batched_wide_border_degree_step_compiles(sds):
+    """The spam deployment's degree 2 as the class-batched fit dispatches
+    it: two classes, n = 57, Lcap = Kcap = 2048 (a border of 1,653), the
+    CG oracle's while_loop under the vmap, both kernels forced."""
+    import dataclasses
+
+    from repro.configs import oavi_paper
+
+    cfg = dataclasses.replace(oavi_paper.cgavi_ihb(), kernel="pallas")
+    k, m, n, Lcap, Kcap = 2, 2048, 57, 2048, 2048
+    state = jax.eval_shape(
+        lambda: ihb_mod.batch_state(
+            ihb_mod.init_state(Lcap, jnp.asarray(1.0, F32), F32, factors=cfg.ihb_factors()), k)
+    )
+    state = jax.tree.map(lambda a: sds(a.shape, a.dtype), state)
+    step = _make_degree_step(cfg)
+    compiled = jax.jit(jax.vmap(step)).lower(
+        sds((k, m, Lcap)), sds((k, m, n)), state, sds((k,), I32),
+        sds((k, Kcap), I32), sds((k, Kcap), I32), sds((k, Kcap), jnp.bool_), sds((k,)),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2, "expected the Gram and IHB kernels"
 
 
 # (m, p): the SVM features of the benchmark's cells, Appendix C and credit

@@ -1,5 +1,7 @@
 """Per-kernel validation: shape/dtype sweeps, interpret=True vs ref oracle."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -67,6 +69,68 @@ def test_gram_gather_ref_bit_exact_vs_onehot_ref():
     g_ops = ops.gram_update(A, X, parents, vars_, use_pallas=False)
     for a, b in zip(g_gather, g_ops):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+# (m, L, n, K, tl, tk): both outputs over several tiles, the cross-Gram
+# over fewer row tiles than the candidate Gram and the other way round,
+# and rectangular tiles.  256-wide tiles and wider: XLA's CPU dot sums a
+# 128 x 128 output over 256 rows in another order than the same entries of
+# a wider product, a trait of the CPU backend and not of the kernel.
+_TILED = [
+    (1024, 768, 57, 768, 256, 256),
+    (768, 512, 57, 768, 256, 256),
+    (512, 768, 5, 256, 256, 256),
+    (512, 256, 5, 1024, 256, 512),
+]
+
+
+@pytest.mark.parametrize("m,L,n,K,tl,tk", _TILED)
+def test_gram_update_acc_tiled_bit_identical(monkeypatch, m, L, n, K, tl, tk):
+    """The kernel tiled over (L, K), in interpret mode, equals the blocked
+    reference bit for bit, carry-in included; row chunks fed one call at a
+    time equal one call.  A VMEM budget that holds just the ``(tl, tk)``
+    tiles forces them at these small shapes."""
+    from repro.kernels import gram_update
+
+    bm = ops.GRAM_BLOCK
+    monkeypatch.setattr(gram_update, "_VMEM_BUDGET", gram_update.vmem_bytes(L, K, n, bm, tl, tk))
+    assert gram_update.tiles(L, K, n, bm) == (tl, tk)
+    # no trace made under another budget is reused here, nor this one later
+    gram_update.gram_update_acc.clear_cache()
+    rng = np.random.default_rng(m + L + K)
+    A = jnp.asarray(rng.uniform(0, 1, (m, L)), jnp.float32)
+    X = jnp.asarray(rng.uniform(0, 1, (m, n)), jnp.float32)
+    parents = jnp.asarray(rng.integers(0, L, K), jnp.int32)
+    vars_ = jnp.asarray(rng.integers(0, n, K), jnp.int32)
+    ql0 = jnp.asarray(rng.uniform(0, 1, (L, K)), jnp.float32)
+    c0 = jnp.asarray(rng.uniform(0, 1, (K, K)), jnp.float32)
+    Psel, Vsel = ops.selection_matrices(parents, vars_, L, n)
+    tiled = functools.partial(gram_update.gram_update_acc, bm=bm, interpret=True)
+    try:
+        got = tiled(A, X, Psel, Vsel, ql0, c0)
+        chunked = (ql0, c0)
+        for lo in range(0, m, bm):
+            chunked = tiled(A[lo : lo + bm], X[lo : lo + bm], Psel, Vsel, *chunked)
+    finally:
+        gram_update.gram_update_acc.clear_cache()
+    want = ref.gram_accumulate_ref(A, X, parents, vars_, ql0, c0, bm=bm)
+    for g, w, c in zip(got, want, chunked):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+        assert np.array_equal(np.asarray(c), np.asarray(g))
+
+
+@pytest.mark.parametrize("L,K,n,tiles", [
+    (64, 64, 3, (64, 64)),  # Appendix C: the whole Grams, one tile
+    (512, 512, 22, (512, 512)),  # credit
+    (2048, 64, 57, (2048, 64)),  # a narrow border after a wide one
+    (2048, 2048, 57, (512, 512)),  # spam's degree 2
+])
+def test_gram_tiles_fit_the_vmem_plan(L, K, n, tiles):
+    from repro.kernels import gram_update
+
+    bm = ops.GRAM_BLOCK
+    assert gram_update.tiles(L, K, n, bm) == tiles
+    assert gram_update.vmem_bytes(L, K, n, bm, *tiles) <= gram_update._VMEM_BUDGET
 
 
 @settings(max_examples=10, deadline=None)

@@ -1,10 +1,8 @@
 """Solver tests: AGD / CG / PCG / BPCG on OAVI's quadratic (CCOP) problems.
 
-The fixed-schedule twins (``solve_*_scheduled``) are tested for *bitwise*
-parity against the while_loop refs: both disciplines run the same
-cond/body/finish closures, so at a sufficient budget every field of the
-result must be identical, and under ``vmap`` each lane must reproduce its
-single-solve bits exactly.
+Under ``vmap`` (the class-batched and streaming fits) the batched
+``while_loop`` runs until its slowest lane stops: a stopped lane's state must
+not move, and each lane should reproduce its single solve's bits.
 """
 
 import jax
@@ -14,13 +12,9 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core.oracles import (
-    SCHEDULED_SOLVERS,
     SOLVERS as ORACLE_SOLVERS,
     OracleConfig,
-    escalate_schedule,
-    max_schedule,
     quad_f,
-    schedule_budget,
     solve_agd,
     solve_bpcg,
     solve_cg,
@@ -96,131 +90,75 @@ def test_warm_start_reduces_iterations():
 ALL_NAMES = ["agd", "cg", "pcg", "bpcg"]
 
 
-def _assert_same_result(ref, sch, *, name=""):
-    assert np.array_equal(np.asarray(ref.y), np.asarray(sch.y)), f"{name}: y"
-    assert np.asarray(ref.f) == np.asarray(sch.f), f"{name}: f"
-    assert np.asarray(ref.gap) == np.asarray(sch.gap), f"{name}: gap"
-    assert int(ref.iters) == int(sch.iters), f"{name}: iters"
+def _assert_same_result(ref, lane, *, name=""):
+    assert np.array_equal(np.asarray(ref.y), np.asarray(lane.y)), f"{name}: y"
+    assert np.asarray(ref.f) == np.asarray(lane.f), f"{name}: f"
+    assert np.asarray(ref.gap) == np.asarray(lane.gap), f"{name}: gap"
+    assert int(ref.iters) == int(lane.iters), f"{name}: iters"
 
 
-def _solve_args(seed, **pkw):
-    Q, q, btb, mask, *_ = _problem(seed, **pkw)
-    return (
-        jnp.asarray(Q), jnp.asarray(q), jnp.asarray(btb), jnp.asarray(1.0),
-        jnp.asarray(mask),
-    )
+def _stack(probs, warms=None):
+    k, Lcap = len(probs), probs[0][0].shape[0]
+    y0 = jnp.zeros((k, Lcap), jnp.float32) if warms is None else jnp.asarray(warms)
+    return tuple(jnp.stack([jnp.asarray(p[j]) for p in probs]) for j in range(4)) + (y0,)
 
 
-@pytest.mark.parametrize("name", ALL_NAMES)
-def test_scheduled_full_budget_parity(name):
-    """At the max schedule, the fixed-schedule twin is bit-identical to the
-    while_loop ref on every result field (shared cond/body/finish)."""
-    args = _solve_args(3)
-    psi = jnp.asarray(1e-6, jnp.float32)  # force real iterations
-    cfg = CFG[name]
-    ref = ORACLE_SOLVERS[name](*args, psi, cfg, None)
-    sch = SCHEDULED_SOLVERS[name](*args, psi, cfg, None,
-                                  schedule=max_schedule(cfg))
-    assert bool(sch.converged)
-    _assert_same_result(ref, sch, name=name)
+def _batched(name, psi, cfg):
+    def single(Q, q, btb, mask, y0):
+        return ORACLE_SOLVERS[name](Q, q, btb, jnp.asarray(1.0), mask, psi, cfg, y0)
 
-
-@pytest.mark.parametrize("name", ALL_NAMES)
-def test_scheduled_escalation_reaches_while_ref(name):
-    """Escalating an undersized budget (x2 until converged) lands bitwise on
-    the while_loop result: iteration chunks compose exactly, so the longer
-    run replays the shorter one's iterations and continues."""
-    args = _solve_args(4)
-    psi = jnp.asarray(1e-7, jnp.float32)
-    cfg = CFG[name]
-    ref = ORACLE_SOLVERS[name](*args, psi, cfg, None)
-    schedule, escalations = 1, 0
-    while True:
-        sch = SCHEDULED_SOLVERS[name](*args, psi, cfg, None, schedule=schedule)
-        if bool(sch.converged) or schedule >= max_schedule(cfg):
-            break
-        schedule = escalate_schedule(cfg, schedule)
-        escalations += 1
-    assert bool(sch.converged)
-    assert escalations >= 1, "problem too easy to exercise escalation"
-    _assert_same_result(ref, sch, name=name)
+    return single, jax.jit(jax.vmap(single))
 
 
 @pytest.mark.parametrize("name", ["cg", "pcg", "bpcg"])
 def test_scheduled_budget_zero_warm_certificate(name):
-    """Budget 0 = certificate check only: a warm start at the solution makes
-    the entry-gap certificates fire without a single iteration, matching the
-    while ref (which also exits at its first cond evaluation)."""
+    """The entry state carries the true FW gap: a warm start at the solution
+    fires a certificate before the first iteration."""
     Q, q, btb, mask, y_star, f_star = _problem(5)
     warm = np.zeros(Q.shape[0], np.float32)
     warm[: len(y_star)] = y_star
-    args = (jnp.asarray(Q), jnp.asarray(q), jnp.asarray(btb), jnp.asarray(1.0),
-            jnp.asarray(mask))
     psi = jnp.asarray(float(f_star) + 1e-3, jnp.float32)  # warm start vanishes
-    cfg = CFG[name]
-    ref = ORACLE_SOLVERS[name](*args, psi, cfg, jnp.asarray(warm))
-    sch = SCHEDULED_SOLVERS[name](*args, psi, cfg, jnp.asarray(warm), schedule=0)
-    assert bool(sch.converged)
-    assert int(sch.iters) == 0
-    _assert_same_result(ref, sch, name=name)
-
-
-def test_schedule_budget_is_config_only():
-    assert schedule_budget(OracleConfig(schedule=0)) == 0
-    assert schedule_budget(OracleConfig(schedule=3)) == 4
-    assert schedule_budget(OracleConfig(schedule=64, max_iter=16)) == 16
-    assert escalate_schedule(OracleConfig(), 0) == 1
-    assert escalate_schedule(OracleConfig(), 4) == 8
-    assert escalate_schedule(OracleConfig(max_iter=16), 16) == 16
-
-
-@settings(max_examples=20, deadline=None)
-@given(
-    st.integers(0, 10_000),
-    st.integers(2, 7),
-    st.sampled_from(ALL_NAMES),
-    st.sampled_from([2.0, 10.0, 1000.0]),
-    st.sampled_from([1e-7, 1e-3, 0.05]),
-)
-def test_property_scheduled_matches_while(seed, ell, name, tau, psi_val):
-    """Hypothesis sweep over problems, masks, radii and accuracy targets:
-    the fixed-schedule twin at full budget is always bitwise the while ref."""
-    args = _solve_args(seed, m=80, ell=ell, Lcap=8)
-    cfg = OracleConfig(name=name, max_iter=512, eps_frac=1e-3, tau=tau)
-    psi = jnp.asarray(psi_val, jnp.float32)
-    ref = ORACLE_SOLVERS[name](*args, psi, cfg, None)
-    sch = SCHEDULED_SOLVERS[name](*args, psi, cfg, None,
-                                  schedule=max_schedule(cfg))
-    assert bool(sch.converged)
-    _assert_same_result(ref, sch, name=f"{name} seed={seed}")
+    res = ORACLE_SOLVERS[name](
+        jnp.asarray(Q), jnp.asarray(q), jnp.asarray(btb), jnp.asarray(1.0),
+        jnp.asarray(mask), psi, CFG[name], jnp.asarray(warm),
+    )
+    assert int(res.iters) == 0
+    assert float(res.f) <= float(psi)
 
 
 @pytest.mark.parametrize("name", ALL_NAMES)
 def test_scheduled_vmap_bit_identity(name):
-    """A vmapped batch of k scheduled solves is bit-identical to the k
-    single solves — the contract the class-batched fit rides on."""
+    """A vmapped batch of k solves is bit-identical to the k single solves —
+    the contract the class-batched fit rides on."""
     k = 4
     probs = [_problem(10 + i, m=120, ell=3 + i, Lcap=8) for i in range(k)]
-    Qb = jnp.stack([jnp.asarray(p[0]) for p in probs])
-    qb = jnp.stack([jnp.asarray(p[1]) for p in probs])
-    btbb = jnp.stack([jnp.asarray(p[2]) for p in probs])
-    maskb = jnp.stack([jnp.asarray(p[3]) for p in probs])
-    y0b = jnp.zeros((k, 8), jnp.float32)
     psi = jnp.asarray(1e-6, jnp.float32)
     cfg = OracleConfig(name=name, max_iter=256, eps_frac=1e-3, tau=10.0)
-    schedule = max_schedule(cfg)
-
-    def single(Q, q, btb, mask, y0):
-        return SCHEDULED_SOLVERS[name](
-            Q, q, btb, jnp.asarray(1.0), mask, psi, cfg, y0, schedule=schedule
-        )
-
-    batched = jax.jit(jax.vmap(single))(Qb, qb, btbb, maskb, y0b)
+    single, batched = _batched(name, psi, cfg)
+    args = _stack(probs)
+    out = batched(*args)
     for i in range(k):
-        ref = single(Qb[i], qb[i], btbb[i], maskb[i], y0b[i])
-        lane = jax.tree_util.tree_map(lambda a: a[i], batched)
+        ref = single(*(a[i] for a in args))
+        lane = jax.tree_util.tree_map(lambda a: a[i], out)
         _assert_same_result(ref, lane, name=f"{name} lane={i}")
-        assert bool(ref.converged) == bool(lane.converged)
+
+
+@pytest.mark.parametrize("name", ALL_NAMES)
+def test_vmap_stopped_lane_keeps_its_state(name):
+    """A lane that stops at once rides a long batched solve unchanged: its
+    result beside cold lanes that iterate equals its result in a batch that
+    stops at once."""
+    probs = [_problem(30 + i, m=120, ell=5, Lcap=8) for i in range(3)]
+    warms = np.zeros((3, 8), np.float32)
+    warms[:, :5] = probs[0][4]
+    psi = jnp.asarray(float(probs[0][5]) + 1e-3, jnp.float32)  # lane 0 vanishes
+    _, batched = _batched(name, psi, CFG[name])
+    mixed = batched(*_stack(probs, np.where(np.arange(3)[:, None] == 0, warms, 0.0)))
+    alone = batched(*_stack([probs[0]] * 3, warms))
+    assert int(mixed.iters[0]) == 0
+    assert int(mixed.iters[1:].max()) > 0, "no lane iterated"
+    first = jax.tree_util.tree_map(lambda a: a[0], alone)
+    _assert_same_result(first, jax.tree_util.tree_map(lambda a: a[0], mixed), name=name)
 
 
 @settings(max_examples=15, deadline=None)

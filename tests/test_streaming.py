@@ -233,7 +233,7 @@ def test_streaming_fit_chunk_size_invariance_property(chunk_rows):
 def test_streaming_fit_classes_bit_exact_multi_engine():
     """Streaming multi-class fit (one vmapped stats step per degree, no row
     padding) is bit-exact against per-class in-memory fits — for the fast
-    engine AND the oracle engines through the fixed-schedule solvers."""
+    engine AND the oracle engines, their solvers under the vmap."""
     from repro.core.oracles import OracleConfig
 
     sizes = [1500, 900, 1200]

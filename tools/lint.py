@@ -12,15 +12,13 @@ the few legitimate *timestamp* uses (e.g. a registry entry's ``loaded_at``)
 are named in ``TIME_TIME_ALLOWLIST``.
 
 Solver-loop discipline: the batched-solver modules must not grow new
-data-dependent ``lax.while_loop``s — a while_loop under ``vmap`` runs every
-lane to the max trip count with no escape for converged lanes, and its trip
-count is invisible to the schedule-budget machinery.  Any new bounded loop
-there must follow the shared-parts discipline of :mod:`repro.core.oracles`:
-write ``cond``/``body``/``finish`` closures and run them through BOTH
-``_run_while`` (the while_loop ref, for sequential fits and parity tests)
-and ``_run_scheduled`` (the masked fixed-schedule twin the batched paths
-use).  ``WHILE_LOOP_ALLOWLIST`` names the one wrapper that legitimately
-calls ``while_loop``.
+ad-hoc ``lax.while_loop``s — under ``vmap`` a while_loop runs until its
+slowest lane stops, so its body must keep stopped lanes bit-stable.  Any
+new bounded loop there must follow the shared-parts discipline of
+:mod:`repro.core.oracles`: write ``cond``/``body``/``finish`` closures and
+run them through ``_run_while``, the loop the sequential and the batched
+fits share.  ``WHILE_LOOP_ALLOWLIST`` names the one wrapper that
+legitimately calls ``while_loop``.
 
     python tools/lint.py [paths...]     (default: src/repro benchmarks tools)
 
@@ -105,7 +103,7 @@ def _while_loop_violations(path: pathlib.Path, repo_root: pathlib.Path):
                     node.lineno,
                     f"data-dependent lax.while_loop {where} — batched solver "
                     f"modules must use the shared-parts discipline "
-                    f"(cond/body/finish through _run_while AND _run_scheduled); "
+                    f"(cond/body/finish through _run_while); "
                     f"allowlisted wrappers for this module: "
                     f"{sorted(allowed) or '(none)'}",
                 )
